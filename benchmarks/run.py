@@ -429,12 +429,9 @@ def bench_lockstep_jax(waves: int = 6, wave_traces: int = 8, repeats: int = 3):
     (2) >= 2x steady-state speedup over the numpy lockstep engine on
     CPU; plus exact-bookkeeping/allclose parity on one wave.
     """
-    from repro.core import available_backends, simulate_lockstep
+    from repro.core import simulate_lockstep
     from repro.core.simulator import params_delay
 
-    if "jax" not in available_backends():
-        print("lockstepjax.status,0,jax not installed — bench skipped")
-        return
     rounds = 44
     alpha = estimate_alpha(_source())
     names = ("m-sgc", "sr-sgc", "gc", "uncoded")
@@ -516,15 +513,11 @@ def bench_grid_jax(num_specs: int = 64, num_traces: int = 8,
     skips the timing gate (compile-count + parity only).
     """
     from repro.core import (
-        available_backends,
         cache_stats,
         clear_runner_cache,
         grid_plan,
     )
 
-    if "jax" not in available_backends():
-        print("gridjax.status,0,jax not installed — bench skipped")
-        return
     # general-GC s sweep: every spec shares (scheme, n, J, T=0, waitout,
     # cells) — `s` is consumed as a traced threshold, so ONE bucket
     specs = [("gc", {"s": s, "prefer_rep": False})
@@ -748,17 +741,19 @@ def bench_coded_train(n: int = 8, models: int = 4, jobs: int = 24,
                     trainer._job_batch(1), sch.chunk_slots(1),
                     trainer.num_chunks,
                 )
+                # (the step donates params/opt, so feed each output
+                # back in as the next input)
                 w0 = jnp.ones((n, trainer.slots), jnp.float32)
-                out = trainer._step(trainer.params[0], trainer.opt[0],
-                                    coded, w0)
-                jax.block_until_ready(out[0])
+                p0, o0, _ = trainer._step(trainer.params[0],
+                                          trainer.opt[0], coded, w0)
+                jax.block_until_ready(p0)
                 ts = []
                 for _ in range(reps):
                     t0 = time.perf_counter()
-                    out = trainer._step(trainer.params[0],
-                                        trainer.opt[0], coded, w0)
-                    jax.block_until_ready(out[0])
+                    p0, o0, _ = trainer._step(p0, o0, coded, w0)
+                    jax.block_until_ready(p0)
                     ts.append(time.perf_counter() - t0)
+                trainer.params[0], trainer.opt[0] = p0, o0
                 step_ms[label] = 1e3 * float(np.median(ts))
             clock = trainer.run(jobs, sc.delays[0])
             sim_clock[(tr_name, label)] = clock
@@ -1219,12 +1214,16 @@ def _write_json(name: str, seconds: float, status: str, text: str,
 
 
 def main() -> None:
+    from repro.launch.cache import confine_tpu_logs, enable_compile_cache
+
     args = sys.argv[1:]
     if "--list" in args:
         width = max(len(name) for name in BENCHES)
         for name, fn in BENCHES.items():
             print(f"{name:<{width}}  {_bench_description(name, fn)}")
         return
+    confine_tpu_logs()
+    enable_compile_cache()
     json_mode = "--json" in args
     # the -smoke variants are tier-1 stand-ins for their full benches;
     # a no-name invocation (the nightly sweep) runs only the full ones

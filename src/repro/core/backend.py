@@ -14,7 +14,7 @@ The **numpy** backend is the default and is what every bit-for-bit
 guarantee in ``tests/test_lockstep.py`` / ``tests/test_batch_engine.py``
 is stated against (its ``at_*`` helpers mutate in place and return the
 same array, which is safe because kernel states own their arrays).  The
-**jax** backend is registered when jax is importable; its ``at_*``
+**jax** backend is always registered; its ``at_*``
 helpers are non-mutating (``arr.at[idx].set``) and its ``concrete``
 flag is False, which tells the kernels that data-dependent Python
 branching (early exits, ``nonzero`` fancy-indexing) is unavailable —
@@ -26,15 +26,16 @@ docs/scheme_kernels.md).
 
 Set the environment variable ``REPRO_BACKEND=jax`` to select the jax
 backend process-wide (the CI matrix job uses this to run the lockstep
-differential suite on both backends).
+differential suite on both backends); an unknown name raises at import.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
-import warnings
 
+import jax as _jax
+import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
@@ -227,55 +228,49 @@ class _NumpyBackend(Backend):
         return out
 
 
-_REGISTRY: dict[str, Backend] = {"numpy": _NumpyBackend()}
+class _JaxBackend(Backend):
+    name = "jax"
+    xp = jnp
+    concrete = False
 
-try:  # pragma: no cover - exercised only where jax is installed
-    import jax as _jax
-    import jax.numpy as jnp
+    def at_set(self, arr, idx, val):
+        return arr.at[idx].set(val)
 
-    class _JaxBackend(Backend):
-        name = "jax"
-        xp = jnp
-        concrete = False
+    def at_or(self, arr, idx, val):
+        # single scatter, no gather: max == or for bools; for int
+        # flag-words apply the OR to the selected elements in place
+        if arr.dtype == jnp.bool_:
+            return arr.at[idx].max(val)
+        return arr.at[idx].apply(lambda x: x | val)
 
-        def at_set(self, arr, idx, val):
-            return arr.at[idx].set(val)
+    def jit(self, fn, **kwargs):
+        return _jax.jit(fn, **kwargs)
 
-        def at_or(self, arr, idx, val):
-            # single scatter, no gather: max == or for bools; for int
-            # flag-words apply the OR to the selected elements in place
-            if arr.dtype == jnp.bool_:
-                return arr.at[idx].max(val)
-            return arr.at[idx].apply(lambda x: x | val)
+    def scan(self, f, init, xs, length: int | None = None):
+        return _jax.lax.scan(f, init, xs, length=length)
 
-        def jit(self, fn, **kwargs):
-            return _jax.jit(fn, **kwargs)
+    def vmap(self, fn, in_axes=0, out_axes=0):
+        if isinstance(in_axes, list):
+            in_axes = tuple(in_axes)
+        return _jax.vmap(fn, in_axes=in_axes, out_axes=out_axes)
 
-        def scan(self, f, init, xs, length: int | None = None):
-            return _jax.lax.scan(f, init, xs, length=length)
+    def argsort_stable(self, arr, axis: int = -1):
+        return jnp.argsort(arr, axis=axis, stable=True)
 
-        def vmap(self, fn, in_axes=0, out_axes=0):
-            if isinstance(in_axes, list):
-                in_axes = tuple(in_axes)
-            return _jax.vmap(fn, in_axes=in_axes, out_axes=out_axes)
+    def segment_sum(self, data, segment_ids, num_segments: int):
+        return _jax.ops.segment_sum(
+            data, segment_ids, num_segments=num_segments
+        )
 
-        def argsort_stable(self, arr, axis: int = -1):
-            return jnp.argsort(arr, axis=axis, stable=True)
+    @property
+    def lax(self):
+        return _jax.lax
 
-        def segment_sum(self, data, segment_ids, num_segments: int):
-            return _jax.ops.segment_sum(
-                data, segment_ids, num_segments=num_segments
-            )
 
-        @property
-        def lax(self):
-            return _jax.lax
-
-    _REGISTRY["jax"] = _JaxBackend()
-except (ImportError, AttributeError, RuntimeError, OSError):
-    # jax absent or broken (missing shared libs, plugin init failure):
-    # the registry stays numpy-only
-    pass
+_REGISTRY: dict[str, Backend] = {
+    "numpy": _NumpyBackend(),
+    "jax": _JaxBackend(),
+}
 
 _ACTIVE = "numpy"
 
@@ -318,18 +313,9 @@ def xp_of(arr):
     straggler-model hooks run unchanged under ``jit``/``scan``."""
     if isinstance(arr, np.ndarray) or np.isscalar(arr):
         return np
-    if "jax" in _REGISTRY:
-        return _REGISTRY["jax"].xp
-    return np  # pragma: no cover - non-numpy array without jax
+    return jnp
 
 
 _env_backend = os.environ.get("REPRO_BACKEND", "").strip().lower()
 if _env_backend:
-    if _env_backend in _REGISTRY:
-        _ACTIVE = _env_backend
-    else:  # pragma: no cover - mis-set env var
-        warnings.warn(
-            f"REPRO_BACKEND={_env_backend!r} is not available "
-            f"(have: {available_backends()}); staying on numpy",
-            stacklevel=1,
-        )
+    set_backend(_env_backend)

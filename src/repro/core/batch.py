@@ -272,7 +272,7 @@ def simulate_lockstep(
             f"{available_backends()}"
         )
     bk_name = backend if backend is not None else get_backend().name
-    if bk_name == "jax" and "jax" in available_backends():
+    if bk_name == "jax":
         res = _simulate_lockstep_jax(
             name, params, scheme, traces, mu=mu, alpha=alpha, J=J,
             waitout=waitout, seed=seed, strict=strict,
@@ -731,16 +731,15 @@ def _simulate_lockstep_jax(
     """The device-resident lockstep path; ``None`` means "spec not
     stageable, use the numpy engine".
 
-    Runs under a scoped ``enable_x64`` so the float timing math is
+    Runs under a scoped ``jax.enable_x64`` so the float timing math is
     f64 like the oracle — the bool/int bookkeeping then matches the
     numpy engine exactly and loads/runtimes allclose (on CPU typically
     bit-equal, but only allclose is contractual).
     """
     import jax
-    from jax.experimental import enable_x64
 
     key = _jax_runner_key(scheme, params, J, waitout, seed)
-    with enable_x64():
+    with jax.enable_x64(True):
         entry = _runner_cache_lookup(
             key, lambda: _build_jax_runner(scheme, J, waitout)
         )
@@ -917,14 +916,13 @@ def _simulate_batch_fused(entries, traces, out, *, mu, alpha, waitout,
     shape bucket.  Returns the entries left for the per-spec path."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     leftover, buckets = _plan_buckets(
         entries, traces.shape, waitout, strict, out
     )
     if not buckets:
         return leftover
-    with enable_x64():
+    with jax.enable_x64(True):
         for b in buckets:
             entry = _runner_cache_lookup(
                 b.key,
@@ -1012,9 +1010,9 @@ def grid_plan(
     ``J``/``T``, the fused stacked-scalar parameter names), the
     per-spec ``fallback`` list (stageability blockers), or
     ``infeasible`` (the constructor rejected the spec / grid outright
-    — ``strict=False`` None rows).  Purely host-side — works without
-    jax installed — so CLIs and benchmarks can report expected compile
-    counts up front.
+    — ``strict=False`` None rows).  Purely host-side — nothing is
+    traced or compiled — so CLIs and benchmarks can report expected
+    compile counts up front.
     """
     traces = np.asarray(traces, dtype=np.float64)
     if traces.ndim == 2:
@@ -1109,11 +1107,7 @@ def simulate_batch(
         specs, traces, seeds, J, strict, out
     )
     bk_name = backend if backend is not None else get_backend().name
-    if (
-        bk_name == "jax"
-        and "jax" in available_backends()
-        and _fuse_enabled(fuse)
-    ):
+    if bk_name == "jax" and _fuse_enabled(fuse):
         entries = _simulate_batch_fused(
             entries, traces, out, mu=mu, alpha=alpha, waitout=waitout,
             strict=strict,

@@ -191,12 +191,9 @@ def _window_stats(win, B: int):
     """
     xp = xp_of(win)
     if xp is not np and win.shape[-1] >= PALLAS_WINDOW_MIN_N:
-        try:
-            from repro.kernels.gate_window.ops import window_stats
-        except ImportError:  # pragma: no cover - kernels pkg unavailable
-            window_stats = None
-        if window_stats is not None:
-            return window_stats(win, B)
+        from repro.kernels.gate_window.ops import window_stats
+
+        return window_stats(win, B)
     distinct = _any_rows(win).sum(axis=1)
     worker_max = _sum_rows(win).max(axis=1, initial=0)
     round_max = win.sum(axis=2).max(axis=1, initial=0)
@@ -223,12 +220,9 @@ def _buffer_stats(buf, B: int):
     xp = xp_of(buf)
     kh = buf.shape[1]
     if xp is not np and kh and buf.shape[-1] >= PALLAS_WINDOW_MIN_N:
-        try:
-            from repro.kernels.gate_window.ops import buffer_stats
-        except ImportError:  # pragma: no cover - kernels pkg unavailable
-            buffer_stats = None
-        if buffer_stats is not None:
-            return buffer_stats(buf, B)
+        from repro.kernels.gate_window.ops import buffer_stats
+
+        return buffer_stats(buf, B)
     bufact = _any_rows(buf)
     bufcnt = _sum_rows(buf)
     if kh >= B:

@@ -364,7 +364,11 @@ class NetConnection:
 
 def tcp_child_main(spec: tuple, target, setup) -> None:
     """Spawn shim: build the worker's :class:`NetConnection` from the
-    picklable ``spec`` and hand it to the normal worker target."""
+    picklable ``spec`` and hand it to the normal worker target (with
+    jax pinned to the CPU, as for pipe-spawned workers)."""
+    from .worker import pin_to_cpu
+
+    pin_to_cpu()
     addr, worker_id, incarnation = spec
     try:
         conn = NetConnection(addr, worker_id, incarnation)

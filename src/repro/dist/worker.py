@@ -20,7 +20,14 @@ the full-gradient truth its decode certificate checks against):
   ``jax.grad(train.coded.chunk_loss_sum)`` on deterministic
   ``data.token_batch`` chunks of a real (tiny) transformer LM, raveled
   to a flat vector.  Heavier (each child compiles its own jit), kept
-  for the slow suite / example.
+  for the slow suite / example.  Grad mode always computes on the host
+  CPU device, in the workers and in the master's truth alike, so both
+  come from one backend.
+
+Workers model the paper's CPU workers: ``worker_main`` (and the TCP
+shim ``net.tcp_child_main``) pin the child's jax to the CPU before it
+touches a backend, so a master holding an accelerator keeps it to
+itself — a chip serves one process at a time.
 
 Fault enactment (``injection.FaultSpec``): the per-round delay from the
 master's trace is burned before reporting; ``drop_rounds`` suppresses
@@ -36,6 +43,15 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from .injection import FaultSpec, enact_delay
+
+
+def pin_to_cpu() -> None:
+    """Restrict this process's jax to the host CPU.  Must run before
+    jax initialises a backend (the first array op or device query);
+    spawned workers call it first thing."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
 
 
 def linear_job_data(seed: int, job: int, num_rows: int, dim: int):
@@ -87,6 +103,7 @@ class TaskComputer:
         self.cfg = model_cfg
         self.batch_size = batch_size
         self.seq_len = seq_len
+        self._cpu = jax.devices("cpu")[0]
         self._ravel = lambda tree: np.asarray(ravel_pytree(tree)[0])
         self._init_state = init_train_state
         self._grad_fn = jax.jit(
@@ -101,13 +118,14 @@ class TaskComputer:
         if job not in self._jobs:
             if len(self._jobs) > 16:
                 self._jobs.clear()
-            params, _ = self._init_state(
-                self.cfg, jax.random.PRNGKey(self.seed * 100003 + job)
-            )
-            batch = token_batch(
-                self.seed, job, self.batch_size, self.seq_len,
-                self.cfg.vocab_size,
-            )
+            with jax.default_device(self._cpu):
+                params, _ = self._init_state(
+                    self.cfg, jax.random.PRNGKey(self.seed * 100003 + job)
+                )
+                batch = token_batch(
+                    self.seed, job, self.batch_size, self.seq_len,
+                    self.cfg.vocab_size,
+                )
             self._jobs[job] = (params, batch)
         return self._jobs[job]
 
@@ -127,8 +145,9 @@ class TaskComputer:
         import jax
 
         params, batch = self._grad_data(job)
-        cb = jax.tree.map(lambda a: a[lo:hi], batch)
-        return self._ravel(self._grad_fn(params, cb))
+        with jax.default_device(self._cpu):
+            cb = jax.tree.map(lambda a: a[lo:hi], batch)
+            return self._ravel(self._grad_fn(params, cb))
 
     def value(self, item: dict) -> np.ndarray:
         """Coefficient-weighted combination of the item's chunk grads."""
@@ -159,7 +178,8 @@ class TaskComputer:
         import jax
 
         params, batch = self._grad_data(job)
-        return self._ravel(self._grad_fn(params, batch))
+        with jax.default_device(self._cpu):
+            return self._ravel(self._grad_fn(params, batch))
 
 
 @dataclass(frozen=True)
@@ -228,6 +248,7 @@ def _enact_cancellable(conn, worker_id: int, t: int, seconds: float,
 
 def worker_main(conn, setup: WorkerSetup) -> None:
     """Spawn target: serve round messages until stopped or killed."""
+    pin_to_cpu()
     fault = setup.fault
     computer = setup.computer()
     computer.warmup()

@@ -18,4 +18,22 @@ Kernels:
     archs' compute hot-spot).
 """
 
-from . import flash_attention, gc_coding, rmsnorm, ssd_scan  # noqa: F401
+import functools
+
+import jax
+
+
+def native_on_tpu(call, *args):
+    """``call(*args, interpret=...)``, compiled by Mosaic when the
+    enclosing program is lowered for a TPU and interpreted on any other
+    platform.  The choice follows the lowering target rather than
+    ``jax.default_backend()``, so an ahead-of-time compile for a
+    described TPU takes the native kernel as well."""
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=functools.partial(call, interpret=False),
+        default=functools.partial(call, interpret=True),
+    )
+
+
+from . import flash_attention, gc_coding, rmsnorm, ssd_scan  # noqa: E402,F401

@@ -51,6 +51,17 @@ def _row_sum(win):
     return out
 
 
+def _pair_count(win, d: int):
+    """Same-worker straggle pairs exactly ``d`` rounds apart, per cell,
+    as a ``(block_c, 1)`` column.  Rounds fold elementwise first, then
+    lanes reduce with ``keepdims``: Mosaic rejects a reduction to a 1-D
+    value (``.sum(axis=(1, 2))``), so no step may drop to rank 1."""
+    W = win.shape[1]
+    return _row_sum(win[:, : W - d] * win[:, d:]).sum(
+        axis=1, keepdims=True
+    ).astype(jnp.int32)
+
+
 def _stats_kernel(win_ref, distinct_ref, wmax_ref, rmax_ref, pair_ref, *,
                   B: int):
     win = win_ref[...]                       # (block_c, W, n) int32 0/1
@@ -63,9 +74,7 @@ def _stats_kernel(win_ref, distinct_ref, wmax_ref, rmax_ref, pair_ref, *,
     rmax_ref[...] = per_round.max(axis=1, keepdims=True).astype(jnp.int32)
     pair = jnp.zeros((win.shape[0], 1), jnp.int32)
     for d in range(B, W):                    # static: W is tiny
-        pair = pair + (win[:, : W - d] * win[:, d:]).sum(
-            axis=(1, 2), keepdims=False
-        ).astype(jnp.int32)[:, None]
+        pair = pair + _pair_count(win, d)
     pair_ref[...] = pair
 
 
@@ -82,9 +91,7 @@ def _buffer_kernel(buf_ref, act_ref, cnt_ref, md_ref, pair_ref, *, B: int):
         md_ref[...] = jnp.zeros(act_ref.shape, jnp.int32)
     pair = jnp.zeros((buf.shape[0], 1), jnp.int32)
     for d in range(B, kh):
-        pair = pair + (buf[:, : kh - d] * buf[:, d:]).sum(
-            axis=(1, 2), keepdims=False
-        ).astype(jnp.int32)[:, None]
+        pair = pair + _pair_count(buf, d)
     pair_ref[...] = pair
 
 
@@ -103,15 +110,18 @@ def window_stats(win: jax.Array, B: int, *, block_c: int,
     if cells % block_c != 0:
         raise ValueError(f"cells={cells} not divisible by block_c={block_c}")
     grid = (cells // block_c,)
-    outs = pl.pallas_call(
-        functools.partial(_stats_kernel, B=B),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_c, W, n), lambda i: (i, 0, 0))],
-        out_specs=[pl.BlockSpec((block_c, 1), lambda i: (i, 0))] * 4,
-        out_shape=[jax.ShapeDtypeStruct((cells, 1), jnp.int32)] * 4,
-        interpret=interpret,
-        name="gate_window_stats",
-    )(win)
+    # the staged simulator traces under x64, but Mosaic takes only
+    # 32-bit block indices: trace the kernel with 32-bit defaults
+    with jax.enable_x64(False):
+        outs = pl.pallas_call(
+            functools.partial(_stats_kernel, B=B),
+            grid=grid,
+            in_specs=[pl.BlockSpec((block_c, W, n), lambda i: (i, 0, 0))],
+            out_specs=[pl.BlockSpec((block_c, 1), lambda i: (i, 0))] * 4,
+            out_shape=[jax.ShapeDtypeStruct((cells, 1), jnp.int32)] * 4,
+            interpret=interpret,
+            name="gate_window_stats",
+        )(win)
     return tuple(o[:, 0] for o in outs)
 
 
@@ -128,23 +138,24 @@ def buffer_stats(buf: jax.Array, B: int, *, block_c: int,
     if cells % block_c != 0:
         raise ValueError(f"cells={cells} not divisible by block_c={block_c}")
     grid = (cells // block_c,)
-    act, cnt, md, pair = pl.pallas_call(
-        functools.partial(_buffer_kernel, B=B),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_c, kh, n), lambda i: (i, 0, 0))],
-        out_specs=[
-            pl.BlockSpec((block_c, n), lambda i: (i, 0)),
-            pl.BlockSpec((block_c, n), lambda i: (i, 0)),
-            pl.BlockSpec((block_c, n), lambda i: (i, 0)),
-            pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((cells, n), jnp.int32),
-            jax.ShapeDtypeStruct((cells, n), jnp.int32),
-            jax.ShapeDtypeStruct((cells, n), jnp.int32),
-            jax.ShapeDtypeStruct((cells, 1), jnp.int32),
-        ],
-        interpret=interpret,
-        name="gate_buffer_stats",
-    )(buf)
+    with jax.enable_x64(False):    # 32-bit block indices, as above
+        act, cnt, md, pair = pl.pallas_call(
+            functools.partial(_buffer_kernel, B=B),
+            grid=grid,
+            in_specs=[pl.BlockSpec((block_c, kh, n), lambda i: (i, 0, 0))],
+            out_specs=[
+                pl.BlockSpec((block_c, n), lambda i: (i, 0)),
+                pl.BlockSpec((block_c, n), lambda i: (i, 0)),
+                pl.BlockSpec((block_c, n), lambda i: (i, 0)),
+                pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((cells, n), jnp.int32),
+                jax.ShapeDtypeStruct((cells, n), jnp.int32),
+                jax.ShapeDtypeStruct((cells, n), jnp.int32),
+                jax.ShapeDtypeStruct((cells, 1), jnp.int32),
+            ],
+            interpret=interpret,
+            name="gate_buffer_stats",
+        )(buf)
     return act, cnt, md, pair

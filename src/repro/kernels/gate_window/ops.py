@@ -2,9 +2,9 @@
 
 Handles ragged shapes (pad cells to the block multiple, lane-pad n to
 128 — all-False padding never changes any of the four statistics),
-bool -> int32 plumbing, and backend selection: on CPU the kernel runs
-in interpret mode (still jit-staged, so it composes with the lockstep
-``lax.scan``), on TPU it compiles natively.
+bool -> int32 plumbing, and backend selection: lowered for a TPU the
+kernel compiles natively, on any other platform it runs in interpret
+mode (still jit-staged, so it composes with the lockstep ``lax.scan``).
 
 Both entry points accept a leading **spec axis** — ``(specs, cells,
 rounds, n)`` inputs fold into the cells axis (one fused launch over
@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
+from .. import native_on_tpu
 from .gate_window import buffer_stats as _buf_kernel
 from .gate_window import window_stats as _win_kernel
 
@@ -42,7 +43,7 @@ def _padded_i32(win, c_pad: int, n_pad: int):
     return jnp.pad(w32, ((0, c_pad - cells), (0, 0), (0, n_pad - n)))
 
 
-def _window_call(win, B: int, interpret: bool):
+def _window_call(win, B: int, *, interpret: bool):
     cells, _, n = win.shape
     n_pad, block_c, c_pad = _pad_plan(cells, n)
     distinct, worker_max, round_max, pair = _win_kernel(
@@ -57,7 +58,7 @@ def _window_call(win, B: int, interpret: bool):
     )
 
 
-def _buffer_call(buf, B: int, interpret: bool):
+def _buffer_call(buf, B: int, *, interpret: bool):
     cells, _, n = buf.shape
     n_pad, block_c, c_pad = _pad_plan(cells, n)
     act, cnt, md, pair = _buf_kernel(
@@ -82,16 +83,20 @@ def _fold_specs(call, x):
 
 
 @functools.lru_cache(maxsize=None)
-def _vmappable(which: str, B: int, interpret: bool):
+def _vmappable(which: str, B: int, interpret: bool | None):
     """The stats call with a reshape-to-cells ``custom_vmap`` rule, one
     cached instance per (kernel, B, interpret) so jit tracing stays
     stable.  ``jax.vmap`` over it (the grid-fused engine's spec axis)
-    becomes one launch over the folded rows."""
+    becomes one launch over the folded rows.  ``interpret=None`` picks
+    native or interpreted by the lowering platform
+    (:func:`repro.kernels.native_on_tpu`)."""
     call = {"window": _window_call, "buffer": _buffer_call}[which]
 
     @custom_vmap
     def f(x):
-        return call(x, B, interpret)
+        if interpret is None:
+            return native_on_tpu(functools.partial(call, B=B), x)
+        return call(x, B, interpret=interpret)
 
     @f.def_vmap
     def _rule(axis_size, in_batched, x):
@@ -112,9 +117,7 @@ def window_stats(win: jax.Array, B: int, *, interpret: bool | None = None):
     plus the bool pair-violation flag — exactly the
     ``core.straggler._window_stats`` contract.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    fn = _vmappable("window", B, bool(interpret))
+    fn = _vmappable("window", B, interpret)
     if win.ndim == 4:
         return _fold_specs(fn, win)
     return fn(win)
@@ -130,9 +133,7 @@ def buffer_stats(buf: jax.Array, B: int, *, interpret: bool | None = None):
     flag (a leading specs axis on every output for 4-D input) —
     exactly the ``core.straggler._buffer_stats`` contract.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    fn = _vmappable("buffer", B, bool(interpret))
+    fn = _vmappable("buffer", B, interpret)
     if buf.ndim == 4:
         return _fold_specs(fn, buf)
     return fn(buf)

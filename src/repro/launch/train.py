@@ -27,6 +27,7 @@ from repro.configs import ARCHS, get_smoke
 from repro.core import GilbertElliotSource, make_scheme
 from repro.core.gc import make_gradient_code
 from repro.data import gc_chunked_batch, token_batch
+from repro.launch.cache import confine_tpu_logs, enable_compile_cache
 from repro.train import CodedTrainingDriver
 from repro.train.coded import (
     gc_round_weights,
@@ -99,6 +100,8 @@ def main() -> None:
     ap.add_argument("--coded", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    confine_tpu_logs()
+    enable_compile_cache()
 
     if args.demo:
         run_demo(args.scheme, args.jobs, args.workers, args.models, args.seed)

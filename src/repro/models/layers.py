@@ -17,6 +17,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import native_on_tpu
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention import ref as fa_ref
 from repro.kernels.rmsnorm import ops as rn_ops
@@ -130,12 +131,17 @@ def _chunked_attention(q, k, v, *, causal, window, block_k=512):
 
 def multihead_attention(
     q, k, v, *, causal: bool, window: int = 0, use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
+    """``interpret=None`` runs the Pallas kernel natively when lowered
+    for a TPU and interpreted elsewhere (``kernels.native_on_tpu``)."""
     if use_pallas:
-        return fa_ops.attention(
-            q, k, v, causal=causal, window=window, interpret=interpret
+        call = functools.partial(
+            fa_ops.attention, causal=causal, window=window
         )
+        if interpret is None:
+            return native_on_tpu(call, q, k, v)
+        return call(q, k, v, interpret=interpret)
     return _chunked_attention(q, k, v, causal=causal, window=window)
 
 

@@ -74,6 +74,30 @@ def chunk_loss_sum(params, cfg: ModelConfig, chunk_batch) -> jax.Array:
     return logits_loss * n_ex
 
 
+def make_coded_loss(cfg: ModelConfig, num_chunks: int):
+    """The coded step's scalar loss ``(params, coded_batch, weights)``:
+    the decode-weighted sum of every (worker, slot) chunk loss over the
+    job's ``num_chunks * chunk_bs`` examples.  Its gradient is the
+    decoded gradient, which equals the full-batch gradient when the
+    weights solve the scheme's decode."""
+
+    def coded_loss(params, coded_batch, weights):
+        def worker_chunks(wchunks, w_i):
+            def one(chunk, w):
+                return w * chunk_loss_sum(params, cfg, chunk)
+            return jax.vmap(one)(wchunks, w_i).sum()
+
+        per_worker = jax.vmap(worker_chunks, in_axes=(0, 0))(
+            coded_batch, weights
+        )  # (n,)
+        total_examples = (
+            num_chunks * jax.tree.leaves(coded_batch)[0].shape[2]
+        )
+        return per_worker.sum() / total_examples
+
+    return coded_loss
+
+
 def make_coded_train_step(cfg: ModelConfig, n: int, s: int, *,
                           lr: float = 1e-4, weight_decay: float = 0.0,
                           num_chunks: int | None = None):
@@ -93,21 +117,7 @@ def make_coded_train_step(cfg: ModelConfig, n: int, s: int, *,
     batch was split into — the loss normalizer ``num_chunks * chunk_bs``
     must equal the job's true batch size.
     """
-    total_chunks = n if num_chunks is None else num_chunks
-
-    def coded_loss(params, coded_batch, weights):
-        def worker_chunks(wchunks, w_i):
-            def one(chunk, w):
-                return w * chunk_loss_sum(params, cfg, chunk)
-            return jax.vmap(one)(wchunks, w_i).sum()
-
-        per_worker = jax.vmap(worker_chunks, in_axes=(0, 0))(
-            coded_batch, weights
-        )  # (n,)
-        total_examples = (
-            total_chunks * jax.tree.leaves(coded_batch)[0].shape[2]
-        )
-        return per_worker.sum() / total_examples
+    coded_loss = make_coded_loss(cfg, n if num_chunks is None else num_chunks)
 
     def step(params, opt_state, coded_batch, weights):
         loss, grads = jax.value_and_grad(coded_loss)(

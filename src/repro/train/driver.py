@@ -362,11 +362,15 @@ class VectorizedCodedTrainer:
         states = [init_train_state(self.cfg, k) for k in keys]
         self.params = [p for p, _ in states]
         self.opt = [o for _, o in states]
+        # params and optimizer state are donated: the update then reuses
+        # their buffers, so a model costs one copy of its state on the
+        # device instead of two at the step's peak
         self._step = jax.jit(
             make_coded_train_step(
                 self.cfg, sch.n, getattr(sch, "s", 0),
                 lr=self.lr, num_chunks=self.num_chunks,
-            )
+            ),
+            donate_argnums=(0, 1),
         )
         self.losses: dict[int, list] = {m: [] for m in range(self.num_models)}
         self.job_done_time: dict[int, float] = {}

@@ -6,17 +6,9 @@ backend."""
 import numpy as np
 import pytest
 
-from repro.core.backend import available_backends, get_backend, xp_of
+from repro.core.backend import get_backend, xp_of
 
-BACKENDS = [
-    pytest.param(
-        name,
-        marks=()
-        if name in available_backends()
-        else pytest.mark.skip(reason=f"{name} backend not registered"),
-    )
-    for name in ("numpy", "jax")
-]
+BACKENDS = ["numpy", "jax"]
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -60,8 +52,6 @@ def test_at_or_int_semantics(backend):
 
 
 def test_jax_at_helpers_do_not_mutate():
-    if "jax" not in available_backends():
-        pytest.skip("jax backend not registered")
     bk = get_backend("jax")
     a = bk.xp.zeros((2, 3), dtype=bool)
     b = bk.at_set(a, (0, 1), True)
@@ -138,9 +128,8 @@ def test_where_and_lax(backend):
 
 def test_xp_of_dispatch():
     assert xp_of(np.zeros(3)) is np
-    if "jax" in available_backends():
-        bk = get_backend("jax")
-        assert xp_of(bk.xp.zeros(3)) is bk.xp
+    bk = get_backend("jax")
+    assert xp_of(bk.xp.zeros(3)) is bk.xp
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -171,3 +160,19 @@ def test_vmap_hook_tuple_outputs(backend):
     out = bk.vmap(lambda a: (a.min(), a + 1.0))(xp.asarray(A))
     assert np.allclose(np.asarray(out[0]), A.min(axis=1))
     assert np.allclose(np.asarray(out[1]), A + 1.0)
+
+
+def test_unknown_repro_backend_raises_at_import():
+    """``REPRO_BACKEND`` naming no backend is an error, not a silent
+    fallback to numpy."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, REPRO_BACKEND="nope", JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "-c", "import repro.core.backend"],
+        env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert "unknown backend 'nope'" in proc.stderr

@@ -81,8 +81,6 @@ def test_clustered_paths_agree_on_trace_library(spec, backend):
     """Legacy simulate (descriptor collect) == simulate_fast
     (step/collect_jobs) == simulate_lockstep (batched kernel) on every
     scenario, for both clustered baselines, on both backends."""
-    if backend == "jax":
-        pytest.importorskip("jax")
     name, kw = spec
     for sc in _scenarios():
         delays = sc.delays[0]

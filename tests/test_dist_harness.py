@@ -18,7 +18,6 @@ import pytest
 
 from repro.core import (
     GilbertElliotSource,
-    available_backends,
     make_scheme,
     simulate_fast,
     simulate_lockstep,
@@ -132,9 +131,7 @@ def test_dead_worker_becomes_always_straggler_without_poisoning_decode():
 
 
 @pytest.mark.parametrize("backend", [
-    "numpy",
-    pytest.param("jax", marks=pytest.mark.skipif(
-        "jax" not in available_backends(), reason="jax not installed")),
+    "numpy", "jax",
 ])
 def test_dead_worker_row_on_both_backends(backend):
     n, J, r_die, w = 8, 10, 4, 2

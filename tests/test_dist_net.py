@@ -335,3 +335,61 @@ class TestRestrictedUnpickler:
             except OSError:
                 pass
             host.close()
+
+
+# ---------------------------------------------------------------------------
+# one process per chip: spawned workers keep jax on the CPU
+# ---------------------------------------------------------------------------
+
+
+def _pin_probe(conn, setup):
+    """Spawned child: was any jax backend up before ``pin_to_cpu``, and
+    which platform serves jax after it?"""
+    import jax
+    from jax._src import xla_bridge
+
+    from repro.dist.worker import pin_to_cpu
+
+    pinned_by_shim = jax.config.jax_platforms
+    initialised_before = xla_bridge.backends_are_initialized()
+    pin_to_cpu()
+    conn.send({"kind": "ready", "pinned_by_shim": pinned_by_shim,
+               "initialised_before": initialised_before,
+               "platforms": jax.config.jax_platforms,
+               "backends": sorted(xla_bridge.backends()),
+               "platform": jax.devices()[0].platform})
+    try:
+        conn.recv()
+    except (EOFError, OSError):
+        return
+
+
+@pytest.mark.parametrize("transport", ["pipe", "tcp"])
+def test_spawned_worker_pins_jax_to_cpu(monkeypatch, transport):
+    """A harness worker models a CPU worker: in a child spawned with no
+    ``JAX_PLATFORMS`` in its environment, nothing has brought up a jax
+    backend by the time ``pin_to_cpu`` runs, and afterwards jax serves
+    the CPU, so a parent holding the chip keeps it.  The TCP spawn shim
+    pins before it hands over to the worker target."""
+    from repro.dist.transport import start_worker
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    host = None
+    if transport == "pipe":
+        link = start_worker(0, _pin_probe, None)
+    else:
+        host = TcpHost()
+        link = start_worker_tcp(host, 0, _pin_probe, {})
+    try:
+        msg = _wait_recv(link, timeout=60.0)
+        assert msg["kind"] == "ready"
+        assert msg["initialised_before"] is False
+        assert msg["platforms"] == "cpu"
+        assert msg["backends"] == ["cpu"]
+        assert msg["platform"] == "cpu"
+        if transport == "tcp":
+            assert msg["pinned_by_shim"] == "cpu"
+    finally:
+        link.stop()
+        if host is not None:
+            host.close()

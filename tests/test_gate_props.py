@@ -2,6 +2,8 @@
 effective history stays inside the design envelope (Remark 2.3), and
 selective wait-outs never wait more workers than the all-workers rule."""
 
+import copy
+
 import numpy as np
 from _prop import HealthCheck, given, settings, st
 
@@ -67,6 +69,12 @@ def test_gate_always_conforms(n, B, dW, lam, s, seed, density, rounds):
 )
 @settings(**COMMON)
 def test_selective_waits_no_more_than_all(seed, density):
+    """Each round, from the same committed history, the selective gate
+    waits out no more workers than the all-workers rule would, and none
+    when that rule admits the round.  Summed over a run the two rules'
+    histories diverge and the sums are not ordered: seed=4836,
+    density=0.2 waits 7 workers selectively against 6 for the
+    all-workers rule."""
     n, rounds = 10, 15
     model = BurstyModel(1, 2, 3)
     rng = np.random.default_rng(seed)
@@ -74,22 +82,13 @@ def test_selective_waits_no_more_than_all(seed, density):
     costs = rng.random((rounds, n))
 
     sel = ConformanceGate(model, n)
-    total_sel = 0
-    for t in range(rounds):
-        if cands[t].any():
-            _, waited = sel.admit_partial(cands[t], costs[t])
-            total_sel += len(waited)
-        else:
-            sel.force(cands[t])
-
-    allg = ConformanceGate(model, n)
-    total_all = 0
     for t in range(rounds):
         if not cands[t].any():
-            allg.force(cands[t])
-        elif allg.admit(cands[t]):
-            pass
-        else:
-            total_all += int(cands[t].sum())
-            allg.force(np.zeros(n, dtype=bool))
-    assert total_sel <= total_all
+            sel.force(cands[t])
+            continue
+        all_rule = copy.deepcopy(sel)
+        waits_all = 0 if all_rule.admit(cands[t]) else int(cands[t].sum())
+        _, waited = sel.admit_partial(cands[t], costs[t])
+        assert len(waited) <= waits_all
+        assert set(waited) <= set(np.flatnonzero(cands[t]).tolist())
+    assert model.conforms(sel.history)

@@ -3,12 +3,11 @@
 models, and the jax suffix/buffer dispatch in ``core.straggler``
 routes through them at n >= 128 with unchanged verdicts."""
 
+import jax
 import numpy as np
 import pytest
 
-jax = pytest.importorskip("jax")
-
-from repro.core.straggler import (  # noqa: E402
+from repro.core.straggler import (
     PALLAS_WINDOW_MIN_N,
     ArbitraryModel,
     BurstyModel,
@@ -16,7 +15,7 @@ from repro.core.straggler import (  # noqa: E402
     _buffer_stats,
     _window_stats,
 )
-from repro.kernels.gate_window import ops, ref  # noqa: E402
+from repro.kernels.gate_window import ops, ref
 
 
 def _rand_windows(shapes, p=0.25, seed=0):

@@ -13,9 +13,7 @@ variant of ``benchmarks/run.py grid-jax``)."""
 import numpy as np
 import pytest
 
-pytest.importorskip("jax")
-
-from repro.core import (  # noqa: E402
+from repro.core import (
     GilbertElliotSource,
     cache_stats,
     clear_runner_cache,
@@ -24,7 +22,7 @@ from repro.core import (  # noqa: E402
     simulate_batch,
     simulate_fast,
 )
-from repro.core.testing import assert_sim_parity  # noqa: E402
+from repro.core.testing import assert_sim_parity
 
 GE = dict(p_ns=0.08, p_sn=0.6, slow_factor=6.0)
 

@@ -112,6 +112,21 @@ def test_flash_attention_sweep(b, hq, hkv, sq, sk, dh, causal):
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 
 
+def test_attention_layer_pallas_path_matches_jnp_off_tpu():
+    """``multihead_attention(use_pallas=True)`` with no explicit
+    ``interpret`` runs the kernel interpreted off the TPU and agrees
+    with the layer's jnp path."""
+    from repro.models.layers import multihead_attention
+
+    q = randn((1, 4, 128, 64), jnp.float32)
+    k = randn((1, 2, 128, 64), jnp.float32)
+    v = randn((1, 2, 128, 64), jnp.float32)
+    got = jax.jit(lambda q, k, v: multihead_attention(
+        q, k, v, causal=True, use_pallas=True))(q, k, v)
+    want = multihead_attention(q, k, v, causal=True, use_pallas=False)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
 @pytest.mark.parametrize("window", [32, 96, 200])
 def test_flash_attention_sliding_window(window):
     q = randn((1, 4, 256, 64), jnp.float32)

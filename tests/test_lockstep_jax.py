@@ -9,17 +9,15 @@ n >= 128."""
 import numpy as np
 import pytest
 
-pytest.importorskip("jax")
-
-from repro.core import (  # noqa: E402
+from repro.core import (
     GilbertElliotSource,
     make_scheme,
     simulate_batch,
     simulate_fast,
     simulate_lockstep,
 )
-from repro.core.batch import _JAX_RUNNERS  # noqa: E402
-from repro.core.testing import assert_sim_parity  # noqa: E402
+from repro.core.batch import _JAX_RUNNERS
+from repro.core.testing import assert_sim_parity
 
 GE = dict(p_ns=0.08, p_sn=0.6, slow_factor=6.0)
 

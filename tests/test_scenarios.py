@@ -19,7 +19,6 @@ from repro.core import (
     LambdaTraceGenerator,
     StochasticBlockModel,
     TraceModel,
-    available_backends,
     make_scheme,
     simulate,
     simulate_batch,
@@ -220,8 +219,6 @@ def test_hetero_alpha_scalar_paths_bitforbit():
             assert_sim_parity(legacy, rl[c], exact=True)
 
 
-@pytest.mark.skipif("jax" not in available_backends(),
-                    reason="jax backend not registered")
 def test_hetero_alpha_jax_lockstep_allclose():
     n, J = 12, 12
     alpha = LambdaTraceGenerator(n=n, seed=1, hetero=0.4).worker_alpha()
@@ -285,10 +282,7 @@ def test_hetero_alpha_through_round_loads_protocol():
 
 @pytest.mark.parametrize(
     "backend",
-    ["numpy",
-     pytest.param("jax", marks=pytest.mark.skipif(
-         "jax" not in available_backends(),
-         reason="jax backend not registered"))],
+    ["numpy", "jax"],
 )
 def test_sbgc_seed_fan_out_both_backends(backend):
     """sb-gc is seed-sensitive: the batch engine must fan the seed axis
