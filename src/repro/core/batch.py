@@ -47,6 +47,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .. import tracing
 from .backend import available_backends, get_backend
 from .kernel import (
     GateKernel,
@@ -213,6 +214,7 @@ def simulate_lockstep(
     seed: int = 0,
     strict: bool = True,
     backend: str | None = None,
+    call: int | None = None,
 ) -> list[SimResult | None]:
     """Advance one spec through MANY traces in lockstep.
 
@@ -248,6 +250,9 @@ def simulate_lockstep(
     with the per-cell loads still coming from the kernel's
     ``round_loads`` protocol.  Identical broadcasting on every path
     (scalar, numpy lockstep, jax scan, fused grid).
+
+    ``call`` is the span identifier of an enclosing ``simulate_batch``
+    call; a fresh one otherwise.
     """
     traces = np.asarray(traces, dtype=np.float64)
     if traces.ndim == 2:
@@ -275,7 +280,7 @@ def simulate_lockstep(
     if bk_name == "jax":
         res = _simulate_lockstep_jax(
             name, params, scheme, traces, mu=mu, alpha=alpha, J=J,
-            waitout=waitout, seed=seed, strict=strict,
+            waitout=waitout, seed=seed, strict=strict, call=call,
         )
         if res is not None:
             return res
@@ -517,7 +522,8 @@ def _runner_cache_lookup(key: tuple, build):
         _CACHE_COUNTERS["hits"] += 1
         return entry
     _CACHE_COUNTERS["misses"] += 1
-    entry = build()
+    with tracing.span("sim.runner_build"):
+        entry = build()
     if entry is _JAX_UNSUPPORTED:
         while len(_JAX_UNSUPPORTED_VERDICTS) >= _VERDICT_CACHE_CAP:
             _JAX_UNSUPPORTED_VERDICTS.pop(
@@ -580,6 +586,7 @@ def _staged_lockstep_run(kernel, gate, rounds: int, selective: bool,
     jitted runner and the grid-fused (vmapped) bucket runner.  ``mu``,
     ``alpha`` and ``load`` are traced scalars (per-spec lanes of the
     stacked arrays under ``vmap``)."""
+    import jax
     import jax.numpy as jnp
 
     bkj = kernel.bk
@@ -590,7 +597,7 @@ def _staged_lockstep_run(kernel, gate, rounds: int, selective: bool,
     cls, flat0 = state_flatten(kernel.init_state(cells))
     gs0 = gate.init_state(cells)
 
-    def body(carry, xs):
+    def round_body(carry, xs):
         flat, bufs, alive = carry
         t, times = xs
         state = state_unflatten(cls, list(flat))
@@ -605,9 +612,10 @@ def _staged_lockstep_run(kernel, gate, rounds: int, selective: bool,
         gs = GateState(bufs=list(bufs), alive=alive,
                        filled=gate.full, history=None)
         if selective:
-            gs, eff, waited = gate.admit_partial(
-                gs, cand, times, any_cand
-            )
+            with jax.named_scope("gate"):
+                gs, eff, waited = gate.admit_partial(
+                    gs, cand, times, any_cand
+                )
             waited_any = waited.any(axis=1)
             wmax = jnp.where(waited, times, -jnp.inf).max(axis=1)
             dur_w = jnp.maximum(
@@ -616,15 +624,21 @@ def _staged_lockstep_run(kernel, gate, rounds: int, selective: bool,
             duration = jnp.where(waited_any, dur_w, base)
             wflag = waited_any
         else:
-            gs, eff, ok_any = gate.admit_all(gs, cand, any_cand)
+            with jax.named_scope("gate"):
+                gs, eff, ok_any = gate.admit_all(gs, cand, any_cand)
             wflag = any_cand & ~ok_any
             duration = jnp.where(wflag, tmax, base)
-        state = kernel.step(state, t, eff)
+        with jax.named_scope("scheme_step"):
+            state = kernel.step(state, t, eff)
         _, flat = state_flatten(state)
         return (
             (tuple(flat), tuple(gs.bufs), gs.alive),
             (duration, eff, wflag),
         )
+
+    def body(carry, xs):
+        with jax.named_scope("round"):
+            return round_body(carry, xs)
 
     ts = jnp.arange(1, rounds + 1)
     xs = (ts, jnp.swapaxes(times_all, 0, 1))
@@ -715,6 +729,25 @@ def _build_jax_grid_runner(scheme, J: int, waitout: str,
     return bkj.jit(run), kernel0.name
 
 
+def _staged_call(run, traces: np.ndarray, call: int) -> dict:
+    """Upload ``traces``, launch ``run`` on them and fetch its outputs,
+    each step in its own span.  Only the fetch waits for the device:
+    the upload and the launch return as soon as the runtime lets
+    them."""
+    import jax
+
+    with tracing.span("sim.upload", call=call, bytes=int(traces.nbytes)):
+        traces = jax.device_put(traces)
+    with tracing.span("sim.dispatch", call=call):
+        out = run(traces)
+    with tracing.span("sim.fetch", call=call) as sp:
+        host = jax.device_get(out)
+        if tracing.collecting():
+            sp.set_metadata(bytes=sum(int(np.asarray(x).nbytes)
+                                      for x in jax.tree.leaves(host)))
+    return host
+
+
 def _simulate_lockstep_jax(
     name: str,
     params: dict,
@@ -727,6 +760,7 @@ def _simulate_lockstep_jax(
     waitout: str,
     seed: int,
     strict: bool,
+    call: int | None,
 ) -> list[SimResult | None] | None:
     """The device-resident lockstep path; ``None`` means "spec not
     stageable, use the numpy engine".
@@ -749,21 +783,26 @@ def _simulate_lockstep_jax(
         rounds = J + scheme.T
         # alpha may be a per-worker (n,) vector (heterogeneous load
         # slopes); a 0-d array otherwise — jit re-stages per shape
-        out = runner(
-            traces[:, :rounds], float(mu),
-            np.asarray(alpha, dtype=np.float64),
-            float(scheme.normalized_load),
+        alpha_arr = np.asarray(alpha, dtype=np.float64)
+        if call is None:
+            call = tracing.next_call_id()
+        host = _staged_call(
+            lambda traces: runner(
+                traces, float(mu), alpha_arr,
+                float(scheme.normalized_load),
+            ),
+            traces[:, :rounds], call,
         )
-        host = jax.device_get(out)
-    return _assemble_results(
-        kernel_name, scheme.normalized_load, J,
-        np.asarray(host["rt"], dtype=np.float64),
-        np.asarray(host["done_round"]),
-        np.asarray(host["dead"]),
-        np.asarray(host["waitouts"]),
-        np.asarray(host["history"]),
-        strict, None,
-    )
+    with tracing.span("sim.assemble", call=call, cells=len(traces)):
+        return _assemble_results(
+            kernel_name, scheme.normalized_load, J,
+            np.asarray(host["rt"], dtype=np.float64),
+            np.asarray(host["done_round"]),
+            np.asarray(host["dead"]),
+            np.asarray(host["waitouts"]),
+            np.asarray(host["history"]),
+            strict, None,
+        )
 
 
 @dataclass(frozen=True)
@@ -910,16 +949,17 @@ def _plan_buckets(entries, traces_shape, waitout, strict, out):
 
 
 def _simulate_batch_fused(entries, traces, out, *, mu, alpha, waitout,
-                          strict):
+                          strict, call):
     """Run the stageable entries of a grid bucket-by-bucket: one
     ``vmap``-wrapped jitted scan and ONE device->host transfer per
     shape bucket.  Returns the entries left for the per-spec path."""
     import jax
     import jax.numpy as jnp
 
-    leftover, buckets = _plan_buckets(
-        entries, traces.shape, waitout, strict, out
-    )
+    with tracing.span("sim.plan", call=call):
+        leftover, buckets = _plan_buckets(
+            entries, traces.shape, waitout, strict, out
+        )
     if not buckets:
         return leftover
     with jax.enable_x64(True):
@@ -952,18 +992,24 @@ def _simulate_batch_fused(entries, traces, out, *, mu, alpha, waitout,
                 name: jnp.asarray([sc[name] for _, _, sc in b.members])
                 for name in b.fused_names
             }
-            res = runner(mu_s, alpha_s, load_s, fused, traces[:, :rounds])
-            host = jax.device_get(res)
-            for i, (e, scheme, _) in enumerate(b.members):
-                out[e.si, e.ki] = _assemble_results(
-                    kernel_name, scheme.normalized_load, b.J,
-                    np.asarray(host["rt"][i], dtype=np.float64),
-                    np.asarray(host["done_round"][i]),
-                    np.asarray(host["dead"][i]),
-                    np.asarray(host["waitouts"][i]),
-                    np.asarray(host["history"][i]),
-                    strict, None,
-                )
+            host = _staged_call(
+                lambda traces: runner(
+                    mu_s, alpha_s, load_s, fused, traces
+                ),
+                traces[:, :rounds], call,
+            )
+            with tracing.span("sim.assemble", call=call,
+                              cells=S * traces.shape[0]):
+                for i, (e, scheme, _) in enumerate(b.members):
+                    out[e.si, e.ki] = _assemble_results(
+                        kernel_name, scheme.normalized_load, b.J,
+                        np.asarray(host["rt"][i], dtype=np.float64),
+                        np.asarray(host["done_round"][i]),
+                        np.asarray(host["dead"][i]),
+                        np.asarray(host["waitouts"][i]),
+                        np.asarray(host["history"][i]),
+                        strict, None,
+                    )
     return leftover
 
 
@@ -1100,55 +1146,64 @@ def simulate_batch(
     traces = np.asarray(traces, dtype=np.float64)
     if traces.ndim == 2:
         traces = traces[None]
-    num_traces, rounds_avail, n = traces.shape
-
-    out = np.empty((len(specs), len(seeds), num_traces), dtype=object)
-    entries, sensitive_map = _plan_entries(
-        specs, traces, seeds, J, strict, out
-    )
-    bk_name = backend if backend is not None else get_backend().name
-    if bk_name == "jax" and _fuse_enabled(fuse):
-        entries = _simulate_batch_fused(
-            entries, traces, out, mu=mu, alpha=alpha, waitout=waitout,
-            strict=strict,
-        )
-    for e in entries:
-        if has_kernel(e.name):
-            # contract violations already yield None cells under
-            # strict=False; ValueError covers constructors that
-            # reject the fitted J_eff (the probe ran at trace
-            # length, an upper bound)
-            try:
-                row = simulate_lockstep(
-                    e.name, e.params, traces, mu=mu, alpha=alpha, J=e.J,
-                    waitout=waitout, seed=e.seed, strict=strict,
-                    backend=backend,
-                )
-            except ValueError:
-                if strict:
-                    raise
-                row = [None] * num_traces
-        else:
-            row = []
-            for ti in range(num_traces):
+    call = tracing.next_call_id()
+    with tracing.span("sim.batch", call=call) as sp:
+        num_traces, rounds_avail, n = traces.shape
+        out = np.empty((len(specs), len(seeds), num_traces), dtype=object)
+        with tracing.span("sim.plan", call=call):
+            entries, sensitive_map = _plan_entries(
+                specs, traces, seeds, J, strict, out
+            )
+        planned = entries
+        bk_name = backend if backend is not None else get_backend().name
+        if bk_name == "jax" and _fuse_enabled(fuse):
+            entries = _simulate_batch_fused(
+                entries, traces, out, mu=mu, alpha=alpha, waitout=waitout,
+                strict=strict, call=call,
+            )
+        for e in entries:
+            if has_kernel(e.name):
+                # contract violations already yield None cells under
+                # strict=False; ValueError covers constructors that
+                # reject the fitted J_eff (the probe ran at trace
+                # length, an upper bound)
                 try:
-                    scheme = make_scheme(e.name, n, e.J, seed=e.seed,
-                                         **dict(e.params))
-                    row.append(simulate_fast(
-                        scheme, traces[ti], mu=mu, alpha=alpha,
-                        J=e.J, waitout=waitout,
-                    ))
-                except (ValueError, AssertionError):
+                    row = simulate_lockstep(
+                        e.name, e.params, traces, mu=mu, alpha=alpha, J=e.J,
+                        waitout=waitout, seed=e.seed, strict=strict,
+                        backend=backend, call=call,
+                    )
+                except ValueError:
                     if strict:
                         raise
-                    row.append(None)
-        out[e.si, e.ki] = row
-    for si, sensitive in sensitive_map.items():
-        if not sensitive:
-            # load-only results are seed-invariant: broadcast the
-            # SimResult objects (shared, treat as read-only)
-            for ki in range(1, len(seeds)):
-                out[si, ki] = out[si, 0]
+                    row = [None] * num_traces
+            else:
+                row = []
+                for ti in range(num_traces):
+                    try:
+                        scheme = make_scheme(e.name, n, e.J, seed=e.seed,
+                                             **dict(e.params))
+                        row.append(simulate_fast(
+                            scheme, traces[ti], mu=mu, alpha=alpha,
+                            J=e.J, waitout=waitout,
+                        ))
+                    except (ValueError, AssertionError):
+                        if strict:
+                            raise
+                        row.append(None)
+            out[e.si, e.ki] = row
+        if tracing.collecting():
+            # lane-rounds run: the seed-broadcast rows below count once
+            sp.set_metadata(lane_rounds=sum(
+                len(r.round_times) for e in planned
+                for r in out[e.si, e.ki] if r is not None
+            ))
+        for si, sensitive in sensitive_map.items():
+            if not sensitive:
+                # load-only results are seed-invariant: broadcast the
+                # SimResult objects (shared, treat as read-only)
+                for ki in range(1, len(seeds)):
+                    out[si, ki] = out[si, 0]
     return out
 
 

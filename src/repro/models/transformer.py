@@ -216,12 +216,15 @@ def embed_inputs(params, cfg, batch) -> jax.Array:
 def forward(params, cfg: ModelConfig, batch) -> tuple[jax.Array, jax.Array]:
     """Full-sequence logits. Returns (logits (b, s, vocab), aux_loss)."""
     x = embed_inputs(params, cfg, batch)
-    x, aux = _stack_forward(params, cfg, x)
-    x = rmsnorm_apply(params["final_norm"], x, use_pallas=cfg.use_pallas)
-    head = (
-        params["embed"].T if cfg.tie_embeddings else params["head"]
-    )
-    logits = x @ head
+    with jax.named_scope("layers"):
+        x, aux = _stack_forward(params, cfg, x)
+    with jax.named_scope("head"):
+        x = rmsnorm_apply(params["final_norm"], x,
+                          use_pallas=cfg.use_pallas)
+        head = (
+            params["embed"].T if cfg.tie_embeddings else params["head"]
+        )
+        logits = x @ head
     return logits, aux
 
 
@@ -229,15 +232,17 @@ def loss_fn(params, cfg: ModelConfig, batch, *, aux_weight: float = 0.01):
     """Mean CE (next-token for causal LMs, per-frame for encoders)."""
     logits, aux = forward(params, cfg, batch)
     labels = batch["labels"]
-    if cfg.causal:
-        logits = logits[:, :-1]
-        labels = labels[:, 1:]
-    if cfg.frontend == "vision_stub":
-        # labels cover only the text suffix
-        logits = logits[:, -labels.shape[1]:]
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return nll.mean() + aux_weight * aux
+    with jax.named_scope("head"):
+        if cfg.causal:
+            logits = logits[:, :-1]
+            labels = labels[:, 1:]
+        if cfg.frontend == "vision_stub":
+            # labels cover only the text suffix
+            logits = logits[:, -labels.shape[1]:]
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        loss = nll.mean()
+    return loss + aux_weight * aux
 
 
 # ---------------------------------------------------------------------------

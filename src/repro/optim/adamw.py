@@ -31,30 +31,31 @@ def adamw_update(
     eps: float = 1e-8,
     weight_decay: float = 0.0,
 ):
-    step = state.step + 1
-    b1t = 1.0 - b1 ** step.astype(jnp.float32)
-    b2t = 1.0 - b2 ** step.astype(jnp.float32)
+    with jax.named_scope("adamw"):
+        step = state.step + 1
+        b1t = 1.0 - b1 ** step.astype(jnp.float32)
+        b2t = 1.0 - b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v):
-        g32 = g.astype(jnp.float32)
-        m = b1 * m + (1 - b1) * g32
-        v = b2 * v + (1 - b2) * g32 * g32
-        mhat = m / b1t
-        vhat = v / b2t
-        delta = mhat / (jnp.sqrt(vhat) + eps)
-        if weight_decay:
-            delta = delta + weight_decay * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
+        def upd(p, g, m, v):
+            g32 = g.astype(jnp.float32)
+            m = b1 * m + (1 - b1) * g32
+            v = b2 * v + (1 - b2) * g32 * g32
+            mhat = m / b1t
+            vhat = v / b2t
+            delta = mhat / (jnp.sqrt(vhat) + eps)
+            if weight_decay:
+                delta = delta + weight_decay * p.astype(jnp.float32)
+            return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
 
-    flat_p, treedef = jax.tree.flatten(params)
-    flat_g = treedef.flatten_up_to(grads)
-    flat_m = treedef.flatten_up_to(state.m)
-    flat_v = treedef.flatten_up_to(state.v)
-    out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
-    new_p = treedef.unflatten([o[0] for o in out])
-    new_m = treedef.unflatten([o[1] for o in out])
-    new_v = treedef.unflatten([o[2] for o in out])
-    return new_p, AdamWState(step=step, m=new_m, v=new_v)
+        flat_p, treedef = jax.tree.flatten(params)
+        flat_g = treedef.flatten_up_to(grads)
+        flat_m = treedef.flatten_up_to(state.m)
+        flat_v = treedef.flatten_up_to(state.v)
+        out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
+        new_p = treedef.unflatten([o[0] for o in out])
+        new_m = treedef.unflatten([o[1] for o in out])
+        new_v = treedef.unflatten([o[2] for o in out])
+        return new_p, AdamWState(step=step, m=new_m, v=new_v)
 
 
 def cosine_schedule(base_lr: float, warmup: int, total: int):

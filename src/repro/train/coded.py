@@ -87,13 +87,14 @@ def make_coded_loss(cfg: ModelConfig, num_chunks: int):
                 return w * chunk_loss_sum(params, cfg, chunk)
             return jax.vmap(one)(wchunks, w_i).sum()
 
-        per_worker = jax.vmap(worker_chunks, in_axes=(0, 0))(
-            coded_batch, weights
-        )  # (n,)
-        total_examples = (
-            num_chunks * jax.tree.leaves(coded_batch)[0].shape[2]
-        )
-        return per_worker.sum() / total_examples
+        with jax.named_scope("coded_loss"):
+            per_worker = jax.vmap(worker_chunks, in_axes=(0, 0))(
+                coded_batch, weights
+            )  # (n,)
+            total_examples = (
+                num_chunks * jax.tree.leaves(coded_batch)[0].shape[2]
+            )
+            return per_worker.sum() / total_examples
 
     return coded_loss
 

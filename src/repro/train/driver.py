@@ -40,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.schemes import MSGCScheme, Scheme
 from repro.data import chunk_boundaries, classification_batch
 from repro.optim import adamw_init, adamw_update
@@ -390,16 +391,21 @@ class VectorizedCodedTrainer:
         from repro.data import coded_slot_batch
 
         sch = self.scheme
-        coded = coded_slot_batch(
-            self._job_batch(jd.job), sch.chunk_slots(jd.job),
-            self.num_chunks,
-        )
-        w = jnp.asarray(sch.decode_weights(jd))
-        midx = (jd.job - 1) % self.num_models
-        self.params[midx], self.opt[midx], metrics = self._step(
-            self.params[midx], self.opt[midx], coded, w
-        )
-        self.losses[midx].append(float(metrics["loss"]))
+        job = jd.job
+        midx = (job - 1) % self.num_models
+        with tracing.span("train.job", job=job, model=midx):
+            with tracing.span("train.batch", job=job):
+                coded = coded_slot_batch(
+                    self._job_batch(job), sch.chunk_slots(job),
+                    self.num_chunks,
+                )
+                w = jnp.asarray(sch.decode_weights(jd))
+            with tracing.span("train.dispatch", job=job):
+                self.params[midx], self.opt[midx], metrics = self._step(
+                    self.params[midx], self.opt[midx], coded, w
+                )
+            with tracing.span("train.sync", job=job):
+                self.losses[midx].append(float(metrics["loss"]))
 
     def run(self, J: int, delays: np.ndarray) -> float:
         """Run J jobs against the (>= J+T rounds, n) delay profile;
@@ -413,23 +419,28 @@ class VectorizedCodedTrainer:
         gate = ConformanceGate(sch.design_model, n)
         clock = 0.0
 
-        for t in range(1, rounds + 1):
-            times = delays[t - 1] + extra
-            kappa = float(times.min())
-            cutoff = (1.0 + self.mu) * kappa
-            cand = times > cutoff
-            if not cand.any():
-                gate.force(cand)
-                clock += float(min(cutoff, times.max()))
-            else:
-                cand, waited = gate.admit_partial(cand, times)  # Remark 2.3
-                base = float(min(cutoff, times.max())) if cand.any() else cutoff
-                clock += float(max(times[waited].max(), base)) if waited else base
+        with tracing.span("train.run", jobs=J):
+            for t in range(1, rounds + 1):
+                with tracing.span("train.round", t=t):
+                    times = delays[t - 1] + extra
+                    kappa = float(times.min())
+                    cutoff = (1.0 + self.mu) * kappa
+                    cand = times > cutoff
+                    if not cand.any():
+                        gate.force(cand)
+                        clock += float(min(cutoff, times.max()))
+                    else:
+                        # Remark 2.3
+                        cand, waited = gate.admit_partial(cand, times)
+                        base = (float(min(cutoff, times.max()))
+                                if cand.any() else cutoff)
+                        clock += (float(max(times[waited].max(), base))
+                                  if waited else base)
 
-            sch.step(t, cand)
-            for jd in sch.collect_decodes(t):
-                self._apply(jd)
-                self.job_done_time[jd.job] = clock
+                    sch.step(t, cand)
+                    for jd in sch.collect_decodes(t):
+                        self._apply(jd)
+                        self.job_done_time[jd.job] = clock
         missing = [j for j in range(1, J + 1) if j not in self.job_done_time]
         assert not missing, f"jobs unfinished: {missing[:4]}"
         return clock
