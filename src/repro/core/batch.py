@@ -391,47 +391,59 @@ def _assemble_results(
     """Build per-cell ``SimResult``s from lockstep outputs (host side,
     shared by the numpy loop and the jax scan path).
 
-    ``job_done_time=None`` (the jax path) recomputes each job's elapsed
-    time as ``rt[c, :done_round].sum()`` — the same contiguous-row
-    numpy reduction the incremental accounting performs, so both paths
-    agree bitwise given identical ``rt``.
+    Every field comes from whole-member arrays: the dicts and counts
+    from one ``.tolist()`` of each bookkeeping array, each cell's
+    ``round_times`` and ``effective_pattern`` from a copy of its own.
+    ``job_done_time=None`` (the jax path) gathers each job's elapsed
+    time ``rt[c, :done_round[c, j]].sum()`` from the prefix table
+    ``E[t] = rt[:, :t].sum(axis=1)``, ``E[0] = 0``: one reduction per
+    round over all cells instead of one per (cell, job).  Each row of
+    a round's reduction is numpy's pairwise summation over a contiguous
+    run, the reduction the numpy engine's incremental accounting
+    performs, so both paths agree bitwise given identical ``rt``.
+    ``np.cumsum`` sums sequentially and would break that.
     """
-    cells = rt.shape[0]
     if strict and bool(dead.any()):
         bad = np.flatnonzero(dead).tolist()
         raise AssertionError(
             f"{scheme_name}: wait-out contract violated in cell(s) "
             f"{bad[:5]}"
         )
+    valid = ~dead.astype(bool) & (done_round[:, 1:] != 0).all(axis=1)
+    if strict and not valid.all():
+        done = done_round[np.flatnonzero(~valid)[0]]
+        missing = np.flatnonzero(done[1:] == 0) + 1
+        raise AssertionError(
+            f"jobs never finished: {missing.tolist()[:5]}..."
+        )
+    # C order: each cell's sum runs along one contiguous row
+    rt = np.ascontiguousarray(rt, dtype=np.float64)
+    cells, rounds = rt.shape
+    jobs = done_round[:, 1 : J + 1]
+    keys = range(1, J + 1)
     if job_done_time is None:
-        job_done_time = []
-        for c in range(cells):
-            done = done_round[c]
-            job_done_time.append({
-                j: float(rt[c, : int(done[j])].sum())
-                for j in range(1, J + 1)
-                if int(done[j])
-            })
+        prefix = np.zeros((rounds + 1, cells))
+        for t in range(1, rounds + 1):
+            prefix[t] = rt[:, :t].sum(axis=1)
+        times = np.take_along_axis(prefix.T, jobs, axis=1).tolist()
+        job_done_time = [dict(zip(keys, row)) for row in times]
+    total = rt.sum(axis=1).tolist()
+    done_rounds = jobs.tolist()
+    counts = waitouts.tolist()
     results: list[SimResult | None] = []
-    for c in range(cells):
-        done = done_round[c]
-        if bool(dead[c]) or not bool((done[1:] != 0).all()):
-            if strict:
-                missing = np.flatnonzero(done[1:] == 0) + 1
-                raise AssertionError(
-                    f"jobs never finished: {missing.tolist()[:5]}..."
-                )
+    for c, ok in enumerate(valid.tolist()):
+        if not ok:
             results.append(None)
             continue
         results.append(
             SimResult(
                 scheme=scheme_name,
-                total_time=float(rt[c].sum()),
+                total_time=total[c],
                 round_times=rt[c].copy(),
-                job_done_round={j: int(done[j]) for j in range(1, J + 1)},
+                job_done_round=dict(zip(keys, done_rounds[c])),
                 job_done_time=job_done_time[c],
-                waitouts=int(waitouts[c]),
-                effective_pattern=np.ascontiguousarray(history[:, c]),
+                waitouts=counts[c],
+                effective_pattern=history[:, c].copy(),
                 normalized_load=normalized_load,
             )
         )

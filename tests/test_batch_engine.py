@@ -14,6 +14,7 @@ import pytest
 
 from repro.core import (
     GilbertElliotSource,
+    SimResult,
     estimate_alpha,
     get_backend,
     make_scheme,
@@ -147,3 +148,158 @@ def test_fast_path_skips_decode_and_minitasks():
     delays = GilbertElliotSource(n=n, seed=2, **GE).sample_delays(J + 1)
     simulate_fast(sch, delays, alpha=4.0, J=J)
     assert sch.code._matrix is None, "fast path built the encode matrix"
+
+
+# -- result assembly: whole-member arrays against the per-cell oracle --
+
+
+def _assemble_per_cell(scheme_name, normalized_load, J, rt, done_round,
+                       dead, waitouts, history, strict, job_done_time=None):
+    """The per-(cell, job) assembly, one numpy sum per job: the oracle
+    ``batch._assemble_results`` must reproduce field for field."""
+    cells = rt.shape[0]
+    if strict and bool(dead.any()):
+        bad = np.flatnonzero(dead).tolist()
+        raise AssertionError(
+            f"{scheme_name}: wait-out contract violated in cell(s) "
+            f"{bad[:5]}"
+        )
+    if job_done_time is None:
+        job_done_time = []
+        for c in range(cells):
+            done = done_round[c]
+            job_done_time.append({
+                j: float(rt[c, : int(done[j])].sum())
+                for j in range(1, J + 1)
+                if int(done[j])
+            })
+    results = []
+    for c in range(cells):
+        done = done_round[c]
+        if bool(dead[c]) or not bool((done[1:] != 0).all()):
+            if strict:
+                missing = np.flatnonzero(done[1:] == 0) + 1
+                raise AssertionError(
+                    f"jobs never finished: {missing.tolist()[:5]}..."
+                )
+            results.append(None)
+            continue
+        results.append(SimResult(
+            scheme=scheme_name,
+            total_time=float(rt[c].sum()),
+            round_times=rt[c].copy(),
+            job_done_round={j: int(done[j]) for j in range(1, J + 1)},
+            job_done_time=job_done_time[c],
+            waitouts=int(waitouts[c]),
+            effective_pattern=np.ascontiguousarray(history[:, c]),
+            normalized_load=normalized_load,
+        ))
+    return results
+
+
+def _lockstep_outputs(J, T, seed=0, cells=6, n=8):
+    """Lockstep outputs of ``cells`` cells: round times spread over six
+    decades (so summation order shows in the bits), each job decoded
+    within T rounds of its own."""
+    rng = np.random.default_rng(seed)
+    rounds = J + T
+    rt = rng.exponential(size=(cells, rounds)) * 10.0 ** rng.uniform(
+        -3, 3, size=(cells, rounds))
+    done = np.zeros((cells, J + 1), dtype=np.int64)
+    done[:, 1:] = np.arange(1, J + 1) + rng.integers(0, T + 1, (cells, J))
+    dead = np.zeros(cells, dtype=bool)
+    waitouts = rng.integers(0, rounds + 1, cells)
+    history = rng.random((rounds, cells, n)) < 0.8
+    return rt, done, dead, waitouts, history
+
+
+def _dead(out):
+    out[2][4] = True
+
+
+def _unfinished(out):
+    out[1][1, 3] = 0
+    out[1][1, -1] = 0
+
+
+def _dead_after_unfinished(out):
+    _unfinished(out)
+    _dead(out)
+
+
+ASSEMBLY_CASES = {
+    "gc-T0": (40, 0, None),
+    "T2": (40, 2, None),
+    "J1": (1, 1, None),
+    "dead-cell": (20, 1, _dead),
+    "unfinished-job": (20, 1, _unfinished),
+    "dead-after-unfinished": (20, 1, _dead_after_unfinished),
+    "given-job-times": (20, 2, None),
+}
+
+
+@pytest.mark.parametrize("strict", [False, True])
+@pytest.mark.parametrize("case", list(ASSEMBLY_CASES))
+def test_assemble_results_matches_per_cell_oracle(case, strict):
+    """``_assemble_results`` builds every cell's ``SimResult`` from
+    whole-member arrays; each field must equal the per-cell oracle's
+    to the bit, failing cells included (``None``, or under ``strict``
+    the same exception), with Python scalars in the dicts and arrays
+    no two results share."""
+    from repro.core import batch
+
+    J, T, plant = ASSEMBLY_CASES[case]
+    out = _lockstep_outputs(J, T)
+    if plant is not None:
+        plant(out)
+    rt, done, dead, waitouts, history = out
+    given = None
+    if case == "given-job-times":
+        given = [{j: 0.25 * j + c for j in range(1, J + 1)}
+                 for c in range(rt.shape[0])]
+    args = ("m-sgc", 0.375, J, rt, done, dead, waitouts, history, strict)
+
+    if strict and plant is not None:
+        with pytest.raises(AssertionError) as want:
+            _assemble_per_cell(*args)
+        with pytest.raises(AssertionError) as got:
+            batch._assemble_results(*args)
+        assert str(got.value) == str(want.value)
+        return
+
+    want = _assemble_per_cell(*args, given)
+    got = batch._assemble_results(*args, given)
+    assert [r is None for r in got] == [r is None for r in want]
+    assert any(r is not None for r in got)
+    kept = [r for r in got if r is not None]
+    for c, (w, g) in enumerate(zip(want, got)):
+        if w is None:
+            continue
+        assert_sim_parity(w, g, exact=True)
+        if given is not None:
+            assert g.job_done_time is given[c]
+        assert type(g.total_time) is float and type(g.waitouts) is int
+        for d, kind in ((g.job_done_round, int), (g.job_done_time, float)):
+            assert all(type(j) is int for j in d)
+            assert all(type(v) is kind for v in d.values())
+        for a in (g.round_times, g.effective_pattern):
+            assert a.flags.c_contiguous and a.flags.owndata
+            assert not np.shares_memory(a, rt)
+            assert not np.shares_memory(a, history)
+    for i, a in enumerate(kept):
+        for b in kept[i + 1:]:
+            assert not np.shares_memory(a.round_times, b.round_times)
+            assert not np.shares_memory(a.effective_pattern,
+                                        b.effective_pattern)
+
+
+def test_assembly_data_tells_pairwise_from_sequential_sums():
+    """The oracle's data is fit to catch a prefix sum taken with
+    ``np.cumsum``: sequential and pairwise sums differ in the bits of
+    some job's time there."""
+    J = 200
+    rt, done, *_ = _lockstep_outputs(J, 0)
+    sequential = np.cumsum(rt, axis=1)
+    pairwise = np.array([[rt[c, :j].sum() for j in range(1, J + 1)]
+                         for c in range(rt.shape[0])])
+    assert (sequential != pairwise).any()
