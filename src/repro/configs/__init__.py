@@ -1,4 +1,4 @@
-"""Registry of the 10 assigned architectures (+ paper-scale config)."""
+"""Registry of the assigned architectures (+ paper-scale config)."""
 
 from __future__ import annotations
 
@@ -19,6 +19,7 @@ _MODULES = {
     "zamba2-2.7b": "zamba2_2_7b",
     "mamba2-1.3b": "mamba2_1_3b",
     "deepseek-67b": "deepseek_67b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCHS = list(_MODULES)

@@ -40,8 +40,10 @@ SHAPES: dict[str, InputShape] = {
 
 def skip_reason(cfg: ModelConfig, shape: InputShape) -> str | None:
     """None if the (arch, shape) pair runs; else the documented skip."""
-    if shape.mode == "decode" and not cfg.has_decode:
+    if shape.mode == "decode" and not cfg.causal:
         return "encoder-only architecture has no autoregressive decode step"
+    if shape.mode == "decode" and not cfg.has_decode:
+        return "latent attention has no cached decode path yet"
     if (
         shape.name == "long_500k"
         and not cfg.supports_long_context

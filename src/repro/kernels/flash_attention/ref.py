@@ -1,4 +1,7 @@
-"""Pure-jnp oracle: dense GQA attention with causal / sliding-window masks."""
+"""Pure-jnp oracle: dense GQA attention with causal / sliding-window masks.
+
+The value head size may differ from the query/key head size (latent
+attention: 192 against 128); ``scale`` defaults to ``dh ** -0.5``."""
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +14,7 @@ def attention(
     *,
     causal: bool = True,
     window: int = 0,
+    scale: float | None = None,
 ) -> jax.Array:
     b, hq, sq, dh = q.shape
     _, hkv, sk, _ = k.shape
@@ -19,7 +23,7 @@ def attention(
     vx = jnp.repeat(v, group, axis=1)
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q.astype(jnp.float32), kx.astype(jnp.float32)
-    ) * (dh ** -0.5)
+    ) * (dh ** -0.5 if scale is None else scale)
     q_pos = jnp.arange(sq)[:, None]
     k_pos = jnp.arange(sk)[None, :]
     mask = jnp.ones((sq, sk), dtype=bool)

@@ -19,7 +19,8 @@ Parameter layout (dense/moe blocks follow the Megatron pattern):
   ssm in_proj         -> (None, model), out_proj -> (model, None)
   norms / scalars     -> replicated
 
-Leading layer-stack axes (from scan stacking) are never sharded.
+Leading layer-stack axes (from scan stacking: ``layers``, and a MoE
+stack's leading ``dense_layers``) are never sharded.
 """
 
 from __future__ import annotations
@@ -30,6 +31,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.config import ModelConfig
 
 from .mesh import data_axes, model_axis
+
+
+#: top-level parameter groups stacked on a leading layer axis
+STACKS = ("layers", "dense_layers")
 
 
 def _axis_size(mesh, axes) -> int:
@@ -52,7 +57,7 @@ def param_pspec(path: tuple[str, ...], leaf, cfg: ModelConfig, mesh) -> P:
     """PartitionSpec for one parameter leaf (path = key names)."""
     m = model_axis(mesh)
     name = path[-1]
-    stacked = path[0] == "layers"  # leading scan axis
+    stacked = path[0] in STACKS  # leading scan axis
     lead = (None,) if stacked else ()
     shape = leaf.shape[1:] if stacked else leaf.shape
 
@@ -83,7 +88,7 @@ def param_pspec(path: tuple[str, ...], leaf, cfg: ModelConfig, mesh) -> P:
 def _moe_fix(path, leaf, cfg, mesh, base: P) -> P:
     """Expert tensors are 3D; re-route w_gate/w_up to (None, None, model)."""
     name = path[-1]
-    stacked = path[0] == "layers"
+    stacked = path[0] in STACKS
     shape = leaf.shape[1:] if stacked else leaf.shape
     if len(shape) == 3 and name in ("w_gate", "w_up"):
         m = model_axis(mesh)

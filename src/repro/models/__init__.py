@@ -10,6 +10,7 @@ from .transformer import (
     init_params,
     loss_fn,
     prefill,
+    sequence_losses,
 )
 
 __all__ = [
@@ -17,6 +18,7 @@ __all__ = [
     "init_params",
     "forward",
     "loss_fn",
+    "sequence_losses",
     "embed_inputs",
     "init_cache",
     "decode_step",

@@ -27,6 +27,32 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     num_shared_experts: int = 0
     moe_d_ff: int = 0           # per-expert hidden dim (0 -> d_ff)
+    norm_topk_prob: bool = True  # renormalise the top-k router weights
+    # the experts this layer holds, ``held_experts`` from
+    # ``held_expert_start`` (0 -> all): one chip's share under expert
+    # parallelism; the router still scores all ``num_experts``
+    held_expert_start: int = 0
+    held_experts: int = 0
+    # load-balance term: "switch" (over the whole call) or "seq"
+    # (DeepSeek-V2's per-sequence term, a sum over sequences); the
+    # coded step adds it with ``balance_weight``
+    balance: str = "switch"
+    balance_weight: float = 0.0
+    first_k_dense: int = 0       # leading dense-MLP layers of a MoE stack
+
+    # latent attention (DeepSeek-V2 MLA, no q_lora): kv_lora_rank > 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # YaRN rope scaling (factor 0 -> plain rope)
+    rope_factor: float = 0.0
+    rope_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -66,13 +92,27 @@ class ModelConfig:
         return self.head_dim or (self.d_model // max(self.num_heads, 1))
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def held(self) -> int:
+        """How many experts this layer computes (all by default)."""
+        return self.held_experts or self.num_experts
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
     @property
     def has_decode(self) -> bool:
-        """Encoder-only models have no autoregressive decode step."""
-        return self.causal
+        """Encoder-only models have no autoregressive decode step; latent
+        attention has no cached decode path yet."""
+        return self.causal and not self.is_mla
 
     @property
     def supports_long_context(self) -> bool:
@@ -98,23 +138,34 @@ class ModelConfig:
     def param_count(self, active_only: bool = False) -> int:
         d, dh = self.d_model, self.head_dim_
         n_attn_layers, n_ssm_layers = self._layer_split()
-        attn = (
-            d * (self.num_heads * dh)            # q
-            + 2 * d * (self.num_kv_heads * dh)   # k, v
-            + (self.num_heads * dh) * d          # o
-        )
+        if self.is_mla:
+            h, r = self.num_heads, self.kv_lora_rank
+            attn = (
+                d * h * self.qk_head_dim                         # q
+                + d * (r + self.qk_rope_head_dim) + r            # kv_a, norm
+                + r * h * (self.qk_nope_head_dim + self.v_head_dim)  # kv_b
+                + h * self.v_head_dim * d                        # o
+            )
+        else:
+            attn = (
+                d * (self.num_heads * dh)            # q
+                + 2 * d * (self.num_kv_heads * dh)   # k, v
+                + (self.num_heads * dh) * d          # o
+            )
         if self.qkv_bias:
             attn += (self.num_heads + 2 * self.num_kv_heads) * dh
         mlp_dense = 3 * d * self.d_ff            # SwiGLU
         total = 0
         if self.family == "moe":
             e_ff = self.expert_d_ff
-            routed = self.num_experts * 3 * d * e_ff
-            active = self.num_experts_per_tok * 3 * d * e_ff
+            routed = self.held * 3 * d * e_ff
+            active = min(self.num_experts_per_tok, self.held) * 3 * d * e_ff
             shared = self.num_shared_experts * 3 * d * e_ff
             router = d * self.num_experts
             per_layer = attn + router + shared + (active if active_only else routed)
-            total += self.num_layers * (per_layer + 2 * d)
+            dense = self.first_k_dense
+            total += (self.num_layers - dense) * (per_layer + 2 * d)
+            total += dense * (attn + mlp_dense + 2 * d)
         elif self.family in ("ssm", "hybrid"):
             di, st, nh = self.ssm_d_inner, self.ssm_state, self.ssm_heads
             # in_proj(z,x,B,C,dt) + out_proj + conv + A,D

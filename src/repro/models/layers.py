@@ -1,5 +1,7 @@
-"""Shared transformer layers: RMSNorm, RoPE, GQA attention (train /
-prefill / cached decode), SwiGLU MLP, MoE.
+"""Shared transformer layers: RMSNorm, RoPE (plain and YaRN), GQA
+attention (train / prefill / cached decode), latent attention (MLA),
+SwiGLU MLP, and a dropless expert layer that holds a share of the
+experts.
 
 Every layer is a pair (init_fn, apply_fn) operating on plain pytrees —
 no framework dependency, shard_map/pjit friendly.  ``use_pallas``
@@ -12,6 +14,7 @@ lengths.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import jax
@@ -56,15 +59,66 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     )
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (b, h, s, dh); positions: (b, s) or (s,)."""
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_correction_range(dim: int, cfg) -> tuple[int, int]:
+    """The rotary pairs between which YaRN blends interpolated and
+    original frequencies (DeepSeek-V2's ``yarn_find_correction_range``):
+    pairs below ``low`` keep their frequency, pairs above ``high`` are
+    divided by the factor."""
+
+    def pair(rotations):
+        return (dim * math.log(cfg.rope_original_max_pos
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(pair(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(pair(cfg.yarn_beta_slow)), dim - 1)
+    return low, high
+
+
+def yarn_frequencies(dim: int, cfg) -> jax.Array:
+    """YaRN inverse frequencies: ``(f/factor) * ramp + f * (1 - ramp)``
+    with ``ramp`` rising linearly from 0 at ``low`` to 1 at ``high``."""
+    freqs = rope_frequencies(dim, cfg.rope_theta)
+    low, high = yarn_correction_range(dim, cfg)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / cfg.rope_factor * ramp + freqs * (1.0 - ramp)
+
+
+def yarn_cos_sin_scale(cfg) -> float:
+    """The factor on YaRN's cos and sin (1 when both mscales agree)."""
+    return (_yarn_mscale(cfg.rope_factor, cfg.yarn_mscale)
+            / _yarn_mscale(cfg.rope_factor, cfg.yarn_mscale_all_dim))
+
+
+def mla_softmax_scale(cfg) -> float:
+    """``qk_head_dim**-0.5``, times YaRN's ``mscale_all_dim`` squared."""
+    scale = cfg.qk_head_dim ** -0.5
+    if cfg.rope_factor and cfg.yarn_mscale_all_dim:
+        scale *= _yarn_mscale(cfg.rope_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float, *,
+               freqs: jax.Array | None = None,
+               mscale: float = 1.0) -> jax.Array:
+    """x: (b, h, s, dh); positions: (b, s) or (s,).  ``freqs`` replaces
+    the plain inverse frequencies (YaRN); ``mscale`` scales cos and
+    sin."""
     dh = x.shape[-1]
-    freqs = rope_frequencies(dh, theta)                     # (dh/2,)
+    if freqs is None:
+        freqs = rope_frequencies(dh, theta)                 # (dh/2,)
     if positions.ndim == 1:
         positions = positions[None, :]
     angles = positions[..., None].astype(jnp.float32) * freqs  # (b, s, dh/2)
     cos = jnp.cos(angles)[:, None, :, :]
     sin = jnp.sin(angles)[:, None, :, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -73,25 +127,33 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 # -- chunked (flash-style) jnp attention --------------------------------------
 
 
-def _chunked_attention(q, k, v, *, causal, window, block_k=512):
+def _chunked_attention(q, k, v, *, causal, window, block_k=1024,
+                       scale=None):
     """Online-softmax attention via lax.scan over KV blocks.
 
     Pure-jnp twin of the Pallas kernel: O(seq) memory, lowers on every
-    backend, differentiable.  q: (b,hq,sq,dh); k,v: (b,hkv,sk,dh).
+    backend, differentiable.  q: (b,hq,sq,dh); k: (b,hkv,sk,dh); v:
+    (b,hkv,sk,dv).  ``scale`` defaults to ``dh**-0.5``.  Up to one block
+    of keys the dense core runs: a scan of two blocks would stack each
+    block's float32 scores for the backward pass, which costs more than
+    the dense scores it saves.
     """
     b, hq, sq, dh = q.shape
     _, hkv, sk, _ = k.shape
+    dv = v.shape[-1]
     group = hq // hkv
-    scale = dh ** -0.5
     if sk <= block_k:
-        return fa_ref.attention(q, k, v, causal=causal, window=window)
+        return fa_ref.attention(q, k, v, causal=causal, window=window,
+                                scale=scale)
+    if scale is None:
+        scale = dh ** -0.5
     pad = (-sk) % block_k
     if pad:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     nk = k.shape[2] // block_k
     kb = k.reshape(b, hkv, nk, block_k, dh).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(b, hkv, nk, block_k, dh).transpose(2, 0, 1, 3, 4)
+    vb = v.reshape(b, hkv, nk, block_k, dv).transpose(2, 0, 1, 3, 4)
 
     q32 = q.astype(jnp.float32)
     q_pos = jnp.arange(sq)
@@ -120,7 +182,7 @@ def _chunked_attention(q, k, v, *, causal, window, block_k=512):
     init = (
         jnp.full((b, hq, sq, 1), NEG_INF, jnp.float32),
         jnp.zeros((b, hq, sq, 1), jnp.float32),
-        jnp.zeros((b, hq, sq, dh), jnp.float32),
+        jnp.zeros((b, hq, sq, dv), jnp.float32),
     )
     (m, l, acc), _ = jax.lax.scan(
         step, init, (jnp.arange(nk), kb, vb)
@@ -131,18 +193,24 @@ def _chunked_attention(q, k, v, *, causal, window, block_k=512):
 
 def multihead_attention(
     q, k, v, *, causal: bool, window: int = 0, use_pallas: bool = False,
-    interpret: bool | None = None,
+    interpret: bool | None = None, scale: float | None = None,
 ):
     """``interpret=None`` runs the Pallas kernel natively when lowered
-    for a TPU and interpreted elsewhere (``kernels.native_on_tpu``)."""
+    for a TPU and interpreted elsewhere (``kernels.native_on_tpu``).
+    The Pallas kernel takes one head size and the default scale."""
     if use_pallas:
+        if scale is not None or v.shape[-1] != q.shape[-1]:
+            raise NotImplementedError(
+                "the Pallas attention kernel takes one head size and the "
+                "default scale")
         call = functools.partial(
             fa_ops.attention, causal=causal, window=window
         )
         if interpret is None:
             return native_on_tpu(call, q, k, v)
         return call(q, k, v, interpret=interpret)
-    return _chunked_attention(q, k, v, causal=causal, window=window)
+    return _chunked_attention(q, k, v, causal=causal, window=window,
+                              scale=scale)
 
 
 # -- GQA attention block -------------------------------------------------------
@@ -239,6 +307,60 @@ def attention_decode(p, x, cache_k, cache_v, pos, cfg):
     return out @ p["wo"], cache_k, cache_v
 
 
+# -- latent attention (MLA) ----------------------------------------------------
+
+
+def mla_init(key, cfg, dtype):
+    """DeepSeek-V2 attention without a query LoRA: ``wq`` (d, h*(dn+dr)),
+    ``wkv_a`` (d, r+dr) to the latent and the shared rope key, its norm,
+    ``wkv_b`` (r, h*(dn+dv)) to each head's nope key and value, ``wo``."""
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], d, h * (dn + dr), dtype),
+        "wkv_a": dense_init(ks[1], d, r + dr, dtype),
+        "kv_norm": rmsnorm_init(r, dtype),
+        "wkv_b": dense_init(ks[2], r, h * (dn + dv), dtype),
+        "wo": dense_init(ks[3], h * dv, d, dtype),
+    }
+
+
+def mla_apply(p, x, cfg, *, positions=None):
+    """x: (b, s, d).  Each head's query is ``[q_nope | q_pe]`` and its key
+    ``[k_nope | k_pe]``, with one rope key ``k_pe`` per token shared by
+    all heads; rope (YaRN when ``cfg.rope_factor``) acts on the ``_pe``
+    parts only."""
+    b, s, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    if positions is None:
+        positions = jnp.arange(s)
+    with jax.named_scope("mla"):
+        q = (x @ p["wq"]).reshape(b, s, h, dn + dr).transpose(0, 2, 1, 3)
+        kv_a = x @ p["wkv_a"]
+        c_kv = rmsnorm_apply(p["kv_norm"], kv_a[..., :r],
+                             use_pallas=cfg.use_pallas)
+        k_pe = kv_a[..., None, r:].transpose(0, 2, 1, 3)     # (b, 1, s, dr)
+        kv = (c_kv @ p["wkv_b"]).reshape(b, s, h, dn + dv)
+        kv = kv.transpose(0, 2, 1, 3)
+        rope = {}
+        if cfg.rope_factor:
+            rope = dict(freqs=yarn_frequencies(dr, cfg),
+                        mscale=yarn_cos_sin_scale(cfg))
+        q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta, **rope)
+        k_pe = apply_rope(k_pe, positions, cfg.rope_theta, **rope)
+        q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe, (b, h, s, dr))], axis=-1)
+        out = multihead_attention(
+            q, k, kv[..., dn:], causal=cfg.causal,
+            window=cfg.sliding_window, use_pallas=cfg.use_pallas,
+            scale=mla_softmax_scale(cfg),
+        )
+        return out.transpose(0, 2, 1, 3).reshape(b, s, h * dv) @ p["wo"]
+
+
 # -- SwiGLU MLP ---------------------------------------------------------------
 
 
@@ -259,10 +381,12 @@ def mlp_apply(p, x):
 
 
 def moe_init(key, cfg, dtype):
-    d, e_ff, E = cfg.d_model, cfg.expert_d_ff, cfg.num_experts
+    """The router over all ``num_experts``; the weights of the held
+    experts only (``cfg.held``); the shared experts as one SwiGLU."""
+    d, e_ff, E = cfg.d_model, cfg.expert_d_ff, cfg.held
     ks = jax.random.split(key, 5)
     p = {
-        "router": dense_init(ks[0], d, E, dtype),
+        "router": dense_init(ks[0], d, cfg.num_experts, dtype),
         "w_gate": (jax.random.normal(ks[1], (E, d, e_ff)) * d ** -0.5).astype(dtype),
         "w_up": (jax.random.normal(ks[2], (E, d, e_ff)) * d ** -0.5).astype(dtype),
         "w_down": (jax.random.normal(ks[3], (E, e_ff, d)) * e_ff ** -0.5).astype(dtype),
@@ -274,67 +398,124 @@ def moe_init(key, cfg, dtype):
     return p
 
 
-def moe_apply(p, x, cfg, *, capacity_factor: float = 1.25,
-              group_size: int = 1024):
-    """Top-k token-choice MoE with grouped capacity dispatch.
+# Moving rows between tokens and the expert-sorted buffer.  Both
+# directions are masked gathers, forward and backward: the grouped matmul
+# leaves the buffer's rows past the routed ones unwritten, so nothing may
+# read them, and a gather's default transpose (a scatter-add) is slow on
+# the chip.  ``take``: row -> (token, k) pair; ``slot``: pair -> row
+# (clamped); ``held``: the pair's expert is held here; ``routed``: the row
+# holds a pair.
 
-    x: (b, s, d) -> ((b, s, d), aux load-balance loss).
 
-    Tokens are split into groups of ``group_size`` and each group gets a
-    private capacity ``Cg = cf * group_size * K / E`` (the flax/MaxText
-    "dropping" formulation).  The largest intermediates are the
-    (G, Tg, E, Cg) dispatch/combine one-hots; with Tg=1024 their FLOP
-    and byte costs stay <10% of the expert FFN compute for all assigned
-    MoE configs.  Sharding the expert axis of the weights over "model"
-    and the group axis over "data" yields expert parallelism with XLA
-    inserting the all-to-alls.
+def _masked_take(x, idx, ok):
+    return jnp.where(ok[:, None], x[idx], jnp.zeros((), x.dtype))
+
+
+@jax.custom_vjp
+def _dispatch(xt, take, slot, held, routed):
+    """Token rows (N, d) -> buffer rows (rows, d)."""
+    return _masked_take(xt, take // (held.shape[0] // xt.shape[0]), routed)
+
+
+def _dispatch_fwd(xt, take, slot, held, routed):
+    return _dispatch(xt, take, slot, held, routed), (slot, held, xt.shape)
+
+
+def _dispatch_bwd(res, g):
+    slot, held, (n, d) = res
+    dx = _masked_take(g, slot, held).reshape(n, -1, d).sum(1)
+    return dx, None, None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, take, slot, held, routed):
+    """Buffer rows (rows, d) -> pair rows (N*K, d), zero where the pair's
+    expert is held elsewhere."""
+    return _masked_take(ys, slot, held)
+
+
+def _combine_fwd(ys, take, slot, held, routed):
+    return _combine(ys, take, slot, held, routed), (take, routed)
+
+
+def _combine_bwd(res, g):
+    take, routed = res
+    return _masked_take(g, take, routed), None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def moe_apply(p, x, cfg):
+    """Dropless top-k token-choice MoE over the held experts.
+
+    x: (b, s, d) -> (y (b, s, d), balance term per sequence (b,), rows
+    per sequence and held expert (b, held) int32).
+
+    The router scores all ``num_experts`` in float32 and each token
+    takes its top ``K`` (renormalised when ``cfg.norm_topk_prob``).
+    Every (token, held expert) selection is computed: the selections
+    are sorted by held expert into a static buffer of ``tokens *
+    min(K, held)`` rows (a token picks an expert at most once, so
+    nothing is dropped) and the three expert matmuls are grouped
+    matmuls over it (``lax.ragged_dot``), which compute the routed rows
+    only.  Selections of experts held elsewhere add nothing here; the
+    shared experts add for every token.  A token's output depends on
+    that token alone.
+
+    The balance term is ``"seq"`` (DeepSeek-V2: per sequence of length
+    T, ``sum_i (E/(K T)) #{t: i in topk(t)} * mean_t p_{t,i}``) or
+    ``"switch"`` (the call's ``E * sum_i mean p_i * mean f_i / K``,
+    repeated per sequence).
     """
     b, s, d = x.shape
-    E, K = cfg.num_experts, cfg.num_experts_per_tok
-    T = b * s
-    Tg = min(group_size, T)
-    while T % Tg:
-        Tg //= 2
-    G = T // Tg
-    # small groups (decode steps, smoke configs) run dropless so the
-    # cached-decode path reproduces the full forward exactly; large
-    # training groups use the standard capacity-factor dropping.
-    if Tg <= 256:
-        Cg = Tg
-    else:
-        Cg = max(int(capacity_factor * Tg * K / E), 1)
-
-    xt = x.reshape(G, Tg, d)
-    logits = (xt @ p["router"]).astype(jnp.float32)           # (G, Tg, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)             # (G, Tg, K)
-    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    # a token picks each expert at most once -> fold K into the E axis
-    onehot_e = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32).sum(2)  # (G,Tg,E)
-    gate_e = jnp.einsum(
-        "gtk,gtke->gte",
-        gate_vals,
-        jax.nn.one_hot(gate_idx, E, dtype=jnp.float32),
-    )
-    pos_in_e = jnp.cumsum(onehot_e, axis=1) - 1.0             # (G, Tg, E)
-    within = (pos_in_e < Cg) & (onehot_e > 0)
-    dispatch = jax.nn.one_hot(
-        pos_in_e.astype(jnp.int32), Cg, dtype=x.dtype
-    ) * within[..., None].astype(x.dtype)                      # (G,Tg,E,Cg)
-    combine = dispatch * gate_e[..., None].astype(x.dtype)
-
-    xin = jnp.einsum("gtec,gtd->gecd", dispatch, xt)           # (G,E,Cg,d)
-    h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", xin, p["w_gate"]))
-    h = h * jnp.einsum("gecd,edf->gecf", xin, p["w_up"])
-    out_e = jnp.einsum("gecf,efd->gecd", h, p["w_down"])
-    out = jnp.einsum("gtec,gecd->gtd", combine, out_e)
-
+    E, K, H = cfg.num_experts, cfg.num_experts_per_tok, cfg.held
+    N = b * s
+    rows = N * min(K, H)
+    xt = x.reshape(N, d)
+    with jax.named_scope("experts"):
+        with jax.named_scope("router"):
+            logits = xt.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+            probs = jax.nn.softmax(logits, axis=-1)               # (N, E)
+            gate, idx = jax.lax.top_k(probs, K)                   # (N, K)
+            if cfg.norm_topk_prob:
+                gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
+            chosen = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum(1)
+            if cfg.balance == "seq":
+                f = chosen.reshape(b, s, E).sum(1) * (E / (K * s))
+                aux = (f * probs.reshape(b, s, E).mean(1)).sum(-1)
+            else:
+                aux = E * jnp.sum(probs.mean(0) * chosen.mean(0)) / K
+                aux = jnp.broadcast_to(aux, (b,))
+        with jax.named_scope("dispatch"):
+            local = idx - cfg.held_expert_start
+            held = (local >= 0) & (local < H)
+            group = jnp.where(held, local, H).reshape(-1)         # (N*K,)
+            order = jnp.argsort(group, stable=True)
+            take = order[:rows]                                   # row -> pair
+            slot = jnp.zeros_like(order).at[order].set(
+                jnp.arange(N * K, dtype=order.dtype))             # pair -> row
+            slot = jnp.minimum(slot, rows - 1)
+            counts = jax.nn.one_hot(group.reshape(b, s * K), H + 1,
+                                    dtype=jnp.int32).sum(1)[:, :H]
+            sizes = counts.sum(0)
+            held = held.reshape(-1)
+            routed = jnp.arange(rows) < sizes.sum()               # row is used
+            xs = _dispatch(xt, take, slot, held, routed)
+        with jax.named_scope("gmm"):
+            h = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"], sizes))
+            h = h * jax.lax.ragged_dot(xs, p["w_up"], sizes)
+            ys = jax.lax.ragged_dot(h, p["w_down"], sizes)
+        with jax.named_scope("combine"):
+            picked = _combine(ys, take, slot, held, routed).reshape(N, K, d)
+            w = jnp.where(held.reshape(N, K), gate, 0.0).astype(ys.dtype)
+            y = jnp.einsum("nkd,nk->nd", picked, w,
+                           preferred_element_type=jnp.float32)
+            y = y.astype(x.dtype)
     if cfg.num_shared_experts:
-        out = out + mlp_apply(p["shared"], xt)
-
-    # Switch-style load-balance aux loss
-    me = probs.mean(axis=(0, 1))
-    ce = onehot_e.mean(axis=(0, 1))
-    aux = E * jnp.sum(me * ce) / K
-    return out.reshape(b, s, d), aux
+        with jax.named_scope("shared_expert"):
+            y = y + mlp_apply(p["shared"], xt)
+    return y.reshape(b, s, d), aux, counts

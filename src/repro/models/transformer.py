@@ -7,15 +7,19 @@ One parameter pytree + three entry points per model:
   * ``decode_step(params, cfg, cache, token, pos)`` — one-token serve
     step against a KV/state cache (``init_cache`` builds it)
 
-Layer stacks are homogeneous and scanned (``lax.scan`` over stacked
-params) so the lowered HLO stays O(1) in depth — essential for the
-95-layer dry-runs.  The hybrid (zamba2-style) model nests the scan:
-outer scan over groups of ``attn_every`` SSM layers, with one *shared*
-attention block (single weight set) applied between groups.
+Layer stacks are scanned (``lax.scan`` over stacked params) so the
+lowered HLO stays O(1) in depth — essential for the 95-layer dry-runs.
+The hybrid (zamba2-style) model nests the scan: outer scan over groups
+of ``attn_every`` SSM layers, with one *shared* attention block (single
+weight set) applied between groups.  A MoE stack with
+``first_k_dense`` leading dense layers (DeepSeek-V2) scans those
+(``dense_layers``) and then the expert layers (``layers``).
 
 Families:
   dense  — GQA attention + SwiGLU, optional QKV bias / sliding window
-  moe    — dense attention + grouped top-k MoE FFN (+ shared experts)
+  moe    — GQA or latent attention (MLA) + dropless top-k MoE FFN over
+           the held experts (+ shared experts), optional leading dense
+           layers
   ssm    — Mamba2/SSD blocks only (attention-free)
   hybrid — SSM stack + shared attention block every ``attn_every``
   vlm    — dense decoder consuming [patch-embeds | text tokens]
@@ -37,6 +41,8 @@ from .layers import (
     attention_decode,
     attention_init,
     dense_init,
+    mla_apply,
+    mla_init,
     mlp_apply,
     mlp_init,
     moe_apply,
@@ -57,14 +63,19 @@ def _dtype(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def _attn_block_init(key, cfg, dtype):
+def _attn_block_init(key, cfg, dtype, *, experts=None):
+    """One attention block; its FFN is the expert layer when
+    ``experts`` (default: the family is moe), else the dense MLP."""
+    if experts is None:
+        experts = cfg.family == "moe"
     k1, k2, k3, k4 = jax.random.split(key, 4)
+    attn_init = mla_init if cfg.is_mla else attention_init
     p = {
         "norm1": rmsnorm_init(cfg.d_model, dtype),
-        "attn": attention_init(k1, cfg, dtype),
+        "attn": attn_init(k1, cfg, dtype),
         "norm2": rmsnorm_init(cfg.d_model, dtype),
     }
-    if cfg.family == "moe":
+    if experts:
         p["moe"] = moe_init(k2, cfg, dtype)
     else:
         p["mlp"] = mlp_init(k3, cfg.d_model, cfg.d_ff, dtype)
@@ -99,9 +110,14 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         if cfg.family == "hybrid":
             params["shared_attn"] = _attn_block_init(k_shared, cfg, dtype)
     else:
+        dense = cfg.first_k_dense if cfg.family == "moe" else 0
+        if dense:
+            params["dense_layers"] = jax.vmap(
+                lambda k: _attn_block_init(k, cfg, dtype, experts=False)
+            )(layer_keys[:dense])
         params["layers"] = jax.vmap(
             lambda k: _attn_block_init(k, cfg, dtype)
-        )(layer_keys)
+        )(layer_keys[dense:])
     return params
 
 
@@ -135,19 +151,32 @@ def _act_constraint(x, cfg):
     return jax.lax.with_sharding_constraint(x, spec)
 
 
+def _attention(lp, x, cfg):
+    h = rmsnorm_apply(lp["norm1"], x, use_pallas=cfg.use_pallas)
+    if cfg.is_mla:
+        return mla_apply(lp["attn"], h, cfg)
+    return attention_apply(lp["attn"], h, cfg)[0]
+
+
 def _attn_layer_body(x, lp, cfg):
     x = _act_constraint(x, cfg)
-    h, _ = attention_apply(
-        lp["attn"], rmsnorm_apply(lp["norm1"], x, use_pallas=cfg.use_pallas),
-        cfg,
-    )
-    x = x + _act_constraint(h, cfg)
+    x = x + _act_constraint(_attention(lp, x, cfg), cfg)
     hidden = rmsnorm_apply(lp["norm2"], x, use_pallas=cfg.use_pallas)
-    if cfg.family == "moe":
-        h, aux = moe_apply(lp["moe"], hidden, cfg)
-    else:
-        h, aux = mlp_apply(lp["mlp"], hidden), jnp.zeros((), jnp.float32)
-    return x + _act_constraint(h, cfg), aux
+    with jax.named_scope("dense_mlp"):
+        h = mlp_apply(lp["mlp"], hidden)
+    return x + _act_constraint(h, cfg), jnp.zeros((), jnp.float32)
+
+
+def _expert_layer_body(x, lp, cfg):
+    """An expert layer: returns the balance term per sequence and the
+    layer's routing counts (``held_rows``, ``max_expert_rows``)."""
+    x = _act_constraint(x, cfg)
+    x = x + _act_constraint(_attention(lp, x, cfg), cfg)
+    hidden = rmsnorm_apply(lp["norm2"], x, use_pallas=cfg.use_pallas)
+    h, aux, counts = moe_apply(lp["moe"], hidden, cfg)
+    rows = counts.sum(0)
+    stats = {"held_rows": rows.sum(), "max_expert_rows": rows.max()}
+    return x + _act_constraint(h, cfg), (aux, stats)
 
 
 def _ssm_layer_body(x, lp, cfg):
@@ -167,7 +196,27 @@ def _scan(cfg, body, init, xs):
 
 
 def _stack_forward(params, cfg, x):
-    """Run the layer stack; returns (hidden, aux_loss_sum)."""
+    """Run the layer stack; returns (hidden, aux, stats): the auxiliary
+    loss summed over layers (per sequence, (b,), for a MoE stack) and
+    the expert layers' routing counts (empty without experts)."""
+    if cfg.family == "moe":
+        return _moe_stack_forward(params, cfg, x)
+    x, aux = _plain_stack_forward(params, cfg, x)
+    return x, aux, {}
+
+
+def _moe_stack_forward(params, cfg, x):
+    if "dense_layers" in params:
+        body = _remat(lambda h, lp: _attn_layer_body(h, lp, cfg), cfg)
+        x, _ = _scan(cfg, body, x, params["dense_layers"])
+    body = _remat(lambda h, lp: _expert_layer_body(h, lp, cfg), cfg)
+    x, (aux, st) = _scan(cfg, body, x, params["layers"])
+    stats = {"held_rows": st["held_rows"].sum(),
+             "max_expert_rows": st["max_expert_rows"].max()}
+    return x, aux.sum(0), stats
+
+
+def _plain_stack_forward(params, cfg, x):
     if cfg.family in ("ssm", "hybrid"):
         body = _remat(lambda h, lp: _ssm_layer_body(h, lp, cfg), cfg)
         if cfg.family == "ssm" or not cfg.attn_every:
@@ -213,11 +262,11 @@ def embed_inputs(params, cfg, batch) -> jax.Array:
     return tok_embeds
 
 
-def forward(params, cfg: ModelConfig, batch) -> tuple[jax.Array, jax.Array]:
-    """Full-sequence logits. Returns (logits (b, s, vocab), aux_loss)."""
+def _forward(params, cfg: ModelConfig, batch):
+    """(logits, aux as :func:`_stack_forward` gives it, routing counts)."""
     x = embed_inputs(params, cfg, batch)
     with jax.named_scope("layers"):
-        x, aux = _stack_forward(params, cfg, x)
+        x, aux, stats = _stack_forward(params, cfg, x)
     with jax.named_scope("head"):
         x = rmsnorm_apply(params["final_norm"], x,
                           use_pallas=cfg.use_pallas)
@@ -225,12 +274,22 @@ def forward(params, cfg: ModelConfig, batch) -> tuple[jax.Array, jax.Array]:
             params["embed"].T if cfg.tie_embeddings else params["head"]
         )
         logits = x @ head
-    return logits, aux
+    return logits, aux, stats
 
 
-def loss_fn(params, cfg: ModelConfig, batch, *, aux_weight: float = 0.01):
-    """Mean CE (next-token for causal LMs, per-frame for encoders)."""
-    logits, aux = forward(params, cfg, batch)
+def _mean_aux(aux):
+    """A MoE stack's per-sequence term averaged over the sequences."""
+    return aux if aux.ndim == 0 else aux.mean()
+
+
+def forward(params, cfg: ModelConfig, batch) -> tuple[jax.Array, jax.Array]:
+    """Full-sequence logits. Returns (logits (b, s, vocab), aux_loss)."""
+    logits, aux, _ = _forward(params, cfg, batch)
+    return logits, _mean_aux(aux)
+
+
+def _token_nll(logits, cfg: ModelConfig, batch):
+    """Per-token negative log-likelihood (b, s')."""
     labels = batch["labels"]
     with jax.named_scope("head"):
         if cfg.causal:
@@ -240,9 +299,29 @@ def loss_fn(params, cfg: ModelConfig, batch, *, aux_weight: float = 0.01):
             # labels cover only the text suffix
             logits = logits[:, -labels.shape[1]:]
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+
+
+def loss_fn(params, cfg: ModelConfig, batch, *, aux_weight: float = 0.01):
+    """Mean CE (next-token for causal LMs, per-frame for encoders) plus
+    ``aux_weight`` times the auxiliary loss."""
+    logits, aux, _ = _forward(params, cfg, batch)
+    nll = _token_nll(logits, cfg, batch)
+    with jax.named_scope("head"):
         loss = nll.mean()
-    return loss + aux_weight * aux
+    return loss + aux_weight * _mean_aux(aux)
+
+
+def sequence_losses(params, cfg: ModelConfig, batch, *, aux_weight: float):
+    """Each sequence's mean CE plus ``aux_weight`` times its balance
+    term, (b,), and the routing counts.  For a MoE stack every term is
+    one sequence's own, so a batch of sequences gives what each would
+    give alone."""
+    logits, aux, stats = _forward(params, cfg, batch)
+    nll = _token_nll(logits, cfg, batch)
+    with jax.named_scope("head"):
+        loss = nll.mean(-1)
+    return loss + aux_weight * aux, stats
 
 
 # ---------------------------------------------------------------------------
@@ -250,8 +329,16 @@ def loss_fn(params, cfg: ModelConfig, batch, *, aux_weight: float = 0.01):
 # ---------------------------------------------------------------------------
 
 
+def _cached_decode(cfg: ModelConfig) -> None:
+    if cfg.is_mla or cfg.first_k_dense:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention and leading dense layers have "
+            "no cached decode path")
+
+
 def init_cache(cfg: ModelConfig, batch_size: int, max_seq: int, dtype=None):
     """KV / SSM-state cache pytree (stacked on a leading layer axis)."""
+    _cached_decode(cfg)
     dtype = dtype or _dtype(cfg)
     L, dh = cfg.num_layers, cfg.head_dim_
     hkv = cfg.num_kv_heads
@@ -283,7 +370,7 @@ def _attn_decode_body(lp, cfg, x, k_cache, v_cache, pos):
     x = x + h
     hidden = rmsnorm_apply(lp["norm2"], x, use_pallas=cfg.use_pallas)
     if cfg.family == "moe":
-        h, _ = moe_apply(lp["moe"], hidden, cfg)
+        h = moe_apply(lp["moe"], hidden, cfg)[0]
     else:
         h = mlp_apply(lp["mlp"], hidden)
     return x + h, k_cache, v_cache
@@ -294,6 +381,7 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos):
 
     Returns (logits (b, vocab), new_cache).
     """
+    _cached_decode(cfg)
     x = params["embed"][token]
     if cfg.family in ("ssm", "hybrid"):
         x, cache = _decode_ssm_stack(params, cfg, cache, x, pos)
@@ -319,6 +407,7 @@ def prefill(params, cfg: ModelConfig, batch, max_seq: int):
     Returns (logits (b, s, vocab), cache) with the cache padded to
     ``max_seq`` positions, ready for ``decode_step`` at pos = s.
     """
+    _cached_decode(cfg)
     x = embed_inputs(params, cfg, batch)
     b, s, _ = x.shape
     pad = max_seq - s
@@ -333,7 +422,7 @@ def prefill(params, cfg: ModelConfig, batch, max_seq: int):
             h = h + attn_out
             hidden = rmsnorm_apply(lp["norm2"], h, use_pallas=cfg.use_pallas)
             if cfg.family == "moe":
-                m, _ = moe_apply(lp["moe"], hidden, cfg)
+                m = moe_apply(lp["moe"], hidden, cfg)[0]
             else:
                 m = mlp_apply(lp["mlp"], hidden)
             kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))

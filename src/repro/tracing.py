@@ -20,12 +20,18 @@ The spans, outermost first:
 - trainer (``train.driver.VectorizedCodedTrainer``): ``train.run``
   (``jobs``), ``train.round`` (``t``) per round, and per decoded job
   ``train.job`` (``job``, ``model``) holding ``train.batch``,
-  ``train.dispatch`` and ``train.sync``.
+  ``train.dispatch`` and ``train.sync``.  For an expert model
+  ``train.sync`` also carries the step's routing counts, fetched with
+  the loss: ``held_rows`` (routed rows that landed on the experts this
+  chip holds, over all expert layers and chunk passes) and
+  ``max_expert_rows`` (the most rows one held expert got in one layer).
 
 Device ops are named by ``jax.named_scope`` inside the jitted programs
 (``round``, ``gate``, ``scheme_step`` in the simulator's scan;
 ``coded_loss``, ``layers``, ``head``, ``adamw`` in the coded train
-step).  The trace holds each program's optimised HLO, whose
+step; inside ``layers`` for DeepSeek-V2-style models ``mla``,
+``dense_mlp``, ``experts`` holding ``router``, ``dispatch``, ``gmm`` and
+``combine``, and ``shared_expert``).  The trace holds each program's optimised HLO, whose
 instructions carry those scope paths, so nothing here records them.
 """
 

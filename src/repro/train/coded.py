@@ -43,7 +43,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.models import decode_step, loss_fn
+from repro.models import decode_step, loss_fn, sequence_losses
 from repro.models.config import ModelConfig
 from repro.optim import adamw_init, adamw_update
 
@@ -66,35 +66,72 @@ def make_train_step(cfg: ModelConfig, *, lr: float = 1e-4,
 
 def chunk_loss_sum(params, cfg: ModelConfig, chunk_batch) -> jax.Array:
     """SUM-reduced loss over one chunk (partial gradients must add up to
-    the full-batch gradient, so per-chunk reduction is a sum)."""
-    logits_loss = loss_fn(params, cfg, chunk_batch, aux_weight=0.0)
+    the full-batch gradient, so per-chunk reduction is a sum).  The
+    balance term enters with the configuration's ``balance_weight``
+    (0 unless it is a sum over sequences)."""
+    logits_loss = loss_fn(params, cfg, chunk_batch,
+                          aux_weight=_balance_weight(cfg))
     # loss_fn returns a mean over chunk tokens; rescale to a sum over
     # examples so sum over chunks == batch total (uniform seq lengths).
     n_ex = jax.tree.leaves(chunk_batch)[0].shape[0]
     return logits_loss * n_ex
 
 
-def make_coded_loss(cfg: ModelConfig, num_chunks: int):
+def _balance_weight(cfg: ModelConfig) -> float:
+    if cfg.balance_weight and cfg.balance != "seq":
+        raise ValueError(
+            f"{cfg.name}: a {cfg.balance!r} balance term is not a sum over "
+            "chunks; only the per-sequence term can be coded")
+    return cfg.balance_weight
+
+
+def make_coded_loss(cfg: ModelConfig, num_chunks: int, *,
+                    with_stats: bool = False):
     """The coded step's scalar loss ``(params, coded_batch, weights)``:
     the decode-weighted sum of every (worker, slot) chunk loss over the
     job's ``num_chunks * chunk_bs`` examples.  Its gradient is the
     decoded gradient, which equals the full-batch gradient when the
-    weights solve the scheme's decode."""
+    weights solve the scheme's decode.  ``with_stats`` returns ``(loss,
+    routing counts)`` (empty without experts).
 
-    def coded_loss(params, coded_batch, weights):
+    An expert model runs every chunk pass's sequences through the model
+    as one batch, weighting each sequence's loss by its pass's weight:
+    the grouped expert matmul takes one flat row axis (vmapped passes
+    would each need their own), and every term of the loss is one
+    sequence's own (dropless routing, the per-sequence balance term), so
+    a sequence gives what it gives in a pass of its own."""
+
+    def vmapped_loss(params, coded_batch, weights):
         def worker_chunks(wchunks, w_i):
             def one(chunk, w):
                 return w * chunk_loss_sum(params, cfg, chunk)
             return jax.vmap(one)(wchunks, w_i).sum()
 
+        per_worker = jax.vmap(worker_chunks, in_axes=(0, 0))(
+            coded_batch, weights
+        )  # (n,)
+        return per_worker.sum(), {}
+
+    def flat_loss(params, coded_batch, weights):
+        n, slots, chunk_bs = jax.tree.leaves(coded_batch)[0].shape[:3]
+        flat = jax.tree.map(
+            lambda x: x.reshape(n * slots * chunk_bs, *x.shape[3:]),
+            coded_batch)
+        per_seq, stats = sequence_losses(params, cfg, flat,
+                                         aux_weight=_balance_weight(cfg))
+        w = jnp.repeat(weights.reshape(-1), chunk_bs)
+        return (w * per_seq).sum(), stats
+
+    loss_and_stats = flat_loss if cfg.family == "moe" else vmapped_loss
+
+    def coded_loss(params, coded_batch, weights):
         with jax.named_scope("coded_loss"):
-            per_worker = jax.vmap(worker_chunks, in_axes=(0, 0))(
-                coded_batch, weights
-            )  # (n,)
+            total, stats = loss_and_stats(params, coded_batch, weights)
             total_examples = (
                 num_chunks * jax.tree.leaves(coded_batch)[0].shape[2]
             )
-            return per_worker.sum() / total_examples
+            loss = total / total_examples
+        return (loss, stats) if with_stats else loss
 
     return coded_loss
 
@@ -117,17 +154,24 @@ def make_coded_train_step(cfg: ModelConfig, n: int, s: int, *,
     ``num_chunks`` (default ``n``) is how many equal chunks the job's
     batch was split into — the loss normalizer ``num_chunks * chunk_bs``
     must equal the job's true batch size.
+
+    The step's metrics are the loss and, for an expert model, the
+    routing counts over all expert layers and chunk passes:
+    ``held_rows`` (routed rows that landed on held experts) and
+    ``max_expert_rows`` (the most rows one held expert got in one
+    layer).
     """
-    coded_loss = make_coded_loss(cfg, n if num_chunks is None else num_chunks)
+    coded_loss = make_coded_loss(cfg, n if num_chunks is None else num_chunks,
+                                 with_stats=True)
 
     def step(params, opt_state, coded_batch, weights):
-        loss, grads = jax.value_and_grad(coded_loss)(
+        (loss, stats), grads = jax.value_and_grad(coded_loss, has_aux=True)(
             params, coded_batch, weights
         )
         params, opt_state = adamw_update(
             params, grads, opt_state, lr=lr, weight_decay=weight_decay
         )
-        return params, opt_state, {"loss": loss}
+        return params, opt_state, {"loss": loss, **stats}
 
     return step
 

@@ -404,8 +404,12 @@ class VectorizedCodedTrainer:
                 self.params[midx], self.opt[midx], metrics = self._step(
                     self.params[midx], self.opt[midx], coded, w
                 )
-            with tracing.span("train.sync", job=job):
-                self.losses[midx].append(float(metrics["loss"]))
+            with tracing.span("train.sync", job=job) as sync:
+                # the routing counts come back with the loss, in one fetch
+                got = jax.device_get(metrics)
+                self.losses[midx].append(float(got.pop("loss")))
+                if got:
+                    sync.set_metadata(**{k: int(v) for k, v in got.items()})
 
     def run(self, J: int, delays: np.ndarray) -> float:
         """Run J jobs against the (>= J+T rounds, n) delay profile;

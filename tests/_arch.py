@@ -8,6 +8,7 @@ Families -> representative:
   dense attention (GQA, qkv-bias)  qwen2-0.5b
   pure SSM (Mamba2)                mamba2-1.3b
   MoE (+ shared experts)           qwen2-moe-a2.7b
+  latent attention, held experts   deepseek-v2-lite
   audio frontend, non-causal       hubert-xlarge
   vision-prefix                    paligemma-3b
 Slow set: llama3.2-1b, zamba2-2.7b (hybrid), mixtral-8x22b,
@@ -21,6 +22,7 @@ FAST_ARCHS = {
     "qwen2-0.5b",
     "mamba2-1.3b",
     "qwen2-moe-a2.7b",
+    "deepseek-v2-lite",
     "hubert-xlarge",
     "paligemma-3b",
 }

@@ -102,6 +102,7 @@ def test_full_config_matches_assignment(arch):
         "zamba2-2.7b": (54, 2560, 32, 32, 10240, 32000),
         "mamba2-1.3b": (48, 2048, 0, 0, 0, 50280),
         "deepseek-67b": (95, 8192, 64, 8, 22016, 102400),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
     }[arch]
     cfg = get_config(arch)
     got = (
@@ -122,6 +123,12 @@ def test_assignment_extras():
     assert get_config("zamba2-2.7b").ssm_state == 64
     assert get_config("mamba2-1.3b").ssm_state == 128
     assert not get_config("hubert-xlarge").causal
+    ds = get_config("deepseek-v2-lite")
+    assert (ds.num_experts, ds.num_experts_per_tok, ds.num_shared_experts,
+            ds.moe_d_ff, ds.first_k_dense) == (64, 6, 2, 1408, 1)
+    assert (ds.kv_lora_rank, ds.qk_nope_head_dim, ds.qk_rope_head_dim,
+            ds.v_head_dim) == (512, 128, 64, 128)
+    assert ds.held == 64 and not ds.norm_topk_prob
 
 
 def test_smoke_configs_are_reduced():
@@ -140,6 +147,7 @@ def test_param_counts_plausible():
         "deepseek-67b": 67e9,
         "mamba2-1.3b": 1.3e9,
         "qwen2-0.5b": 0.5e9,
+        "deepseek-v2-lite": 15.7e9,
     }
     for arch, target in approx.items():
         n = get_config(arch).param_count()
