@@ -741,17 +741,26 @@ def _build_jax_grid_runner(scheme, J: int, waitout: str,
     return bkj.jit(run), kernel0.name
 
 
-def _staged_call(run, traces: np.ndarray, call: int) -> dict:
-    """Upload ``traces``, launch ``run`` on them and fetch its outputs,
-    each step in its own span.  Only the fetch waits for the device:
-    the upload and the launch return as soon as the runtime lets
-    them."""
+def _upload(traces: np.ndarray, call: int, buckets: int = 1):
+    """Copy ``traces`` to the device in one ``sim.upload`` span, whose
+    ``buckets`` counts the bucket programs that read this copy.  The
+    copy returns as soon as the runtime lets it: the first fetch that
+    needs it waits."""
     import jax
 
-    with tracing.span("sim.upload", call=call, bytes=int(traces.nbytes)):
-        traces = jax.device_put(traces)
+    with tracing.span("sim.upload", call=call, bytes=int(traces.nbytes),
+                      buckets=buckets):
+        return jax.device_put(traces)
+
+
+def _staged_call(run, traces_dev, call: int) -> dict:
+    """Launch ``run`` on the uploaded ``traces_dev`` and fetch its
+    outputs, each step in its own span.  Only the fetch waits for the
+    device: the launch returns as soon as the runtime lets it."""
+    import jax
+
     with tracing.span("sim.dispatch", call=call):
-        out = run(traces)
+        out = run(traces_dev)
     with tracing.span("sim.fetch", call=call) as sp:
         host = jax.device_get(out)
         if tracing.collecting():
@@ -803,7 +812,7 @@ def _simulate_lockstep_jax(
                 traces, float(mu), alpha_arr,
                 float(scheme.normalized_load),
             ),
-            traces[:, :rounds], call,
+            _upload(traces[:, :rounds], call), call,
         )
     with tracing.span("sim.assemble", call=call, cells=len(traces)):
         return _assemble_results(
@@ -964,7 +973,11 @@ def _simulate_batch_fused(entries, traces, out, *, mu, alpha, waitout,
                           strict, call):
     """Run the stageable entries of a grid bucket-by-bucket: one
     ``vmap``-wrapped jitted scan and ONE device->host transfer per
-    shape bucket.  Returns the entries left for the per-spec path."""
+    shape bucket.  Every bucket replays the same traces, so they cross
+    to the device once a call; a bucket whose J+T falls short of the
+    trace length reads a device slice of its rounds, so its program
+    takes only its own J+T.  Returns the entries left for the per-spec
+    path."""
     import jax
     import jax.numpy as jnp
 
@@ -974,7 +987,9 @@ def _simulate_batch_fused(entries, traces, out, *, mu, alpha, waitout,
         )
     if not buckets:
         return leftover
+    rounds_avail = traces.shape[1]
     with jax.enable_x64(True):
+        traces_dev = _upload(traces, call, buckets=len(buckets))
         for b in buckets:
             entry = _runner_cache_lookup(
                 b.key,
@@ -1006,9 +1021,11 @@ def _simulate_batch_fused(entries, traces, out, *, mu, alpha, waitout,
             }
             host = _staged_call(
                 lambda traces: runner(
-                    mu_s, alpha_s, load_s, fused, traces
+                    mu_s, alpha_s, load_s, fused,
+                    traces if rounds == rounds_avail
+                    else traces[:, :rounds],
                 ),
-                traces[:, :rounds], call,
+                traces_dev, call,
             )
             with tracing.span("sim.assemble", call=call,
                               cells=S * traces.shape[0]):

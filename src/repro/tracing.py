@@ -13,10 +13,13 @@ The spans, outermost first:
 
 - simulator (``core.batch``): ``sim.batch`` (``call``,
   ``lane_rounds``) around one ``simulate_batch``; inside it
-  ``sim.plan``, and per shape bucket ``sim.upload`` (``bytes``),
-  ``sim.dispatch`` (the program's launch), ``sim.fetch`` (``bytes``;
-  it waits for the program) and ``sim.assemble`` (``cells``);
-  ``sim.runner_build`` on a runner-cache miss;
+  ``sim.plan``, one ``sim.upload`` (``bytes``; ``buckets``, the bucket
+  programs that read this one copy of the traces), then per shape
+  bucket ``sim.dispatch`` (the launch of its trace slice and its
+  program), ``sim.fetch`` (``bytes``; it waits for the upload and the
+  program) and ``sim.assemble`` (``cells``); ``sim.runner_build`` on a
+  runner-cache miss.  The per-spec path (``fuse=False``) uploads once
+  per spec;
 - trainer (``train.driver.VectorizedCodedTrainer``): ``train.run``
   (``jobs``), ``train.round`` (``t``) per round, and per decoded job
   ``train.job`` (``job``, ``model``) holding ``train.batch``,

@@ -345,6 +345,62 @@ def test_grid_fused_heterogeneous_alpha():
                               exact=False)
 
 
+# buckets of T = 3, 1, 0 and 0: with traces 5 rounds longer than the
+# longest J+T, every bucket reads its rounds as a device slice of the
+# call's one upload
+SLICED = [("m-sgc", {"B": 2, "W": 3, "lam": 5}),
+          ("sr-sgc", {"B": 1, "W": 2, "lam": 3}),
+          ("gc", {"s": 3}), ("uncoded", {})]
+SLICED_J = 10
+
+
+def _fused_equals_perspec(traces):
+    """Fused results against the per-spec runners (each uploads its own
+    slice): exact bookkeeping and round times bit-equal on the CPU."""
+    kw = dict(J=SLICED_J, alpha=6.0, backend="jax")
+    fused = simulate_batch(SLICED, traces, fuse=True, **kw)
+    perspec = simulate_batch(SLICED, traces, fuse=False, **kw)
+    for p, f in zip(perspec.flat, fused.flat, strict=True):
+        assert_sim_parity(p, f, exact=False)
+        assert f.round_times.tobytes() == p.round_times.tobytes()
+    return fused
+
+
+def test_grid_fused_buckets_slice_one_upload(monkeypatch):
+    import jax
+
+    n, cells = 12, 3
+    plan = grid_plan(SLICED, _traces(n, 20, cells), J=SLICED_J)
+    assert sorted(b["T"] for b in plan["buckets"]) == [0, 0, 1, 3]
+    rounds_avail = SLICED_J + 3 + 5
+    traces = _traces(n, rounds_avail, cells, seed0=40)
+    _fused_equals_perspec(traces)
+    uploads = []
+    real = jax.device_put
+    monkeypatch.setattr(
+        jax, "device_put",
+        lambda x, *a, **k: uploads.append(np.shape(x)) or real(x, *a, **k),
+    )
+    simulate_batch(SLICED, traces, J=SLICED_J, alpha=6.0, backend="jax",
+                   fuse=True)
+    assert uploads == [traces.shape]
+
+
+def test_grid_fused_upload_keeps_f64():
+    """The one upload carries the delays at f64: an f32 copy would give
+    the fused buckets other round times than the per-spec runners."""
+    n, cells = 12, 2
+    traces = np.random.default_rng(5).exponential(
+        1.0, size=(cells, SLICED_J + 3 + 5, n))
+    as_f32 = traces.astype(np.float32).astype(np.float64)
+    assert (as_f32 != traces).all()
+    fused = _fused_equals_perspec(traces)
+    rounded = simulate_batch(SLICED, as_f32, J=SLICED_J, alpha=6.0,
+                             backend="jax", fuse=False)
+    assert all(f.round_times.tobytes() != r.round_times.tobytes()
+               for f, r in zip(fused.flat, rounded.flat, strict=True))
+
+
 # (the REPRO_GRID_FUSE toggle/parser matrix lives in
 # tests/test_runner_cache.py::test_grid_fuse_env_parser)
 

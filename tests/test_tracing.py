@@ -80,13 +80,15 @@ def test_simulator_spans_nest_under_one_call(tmp_path):
     assert all(_inside(e, top) and e[3]["call"] == call for e in sim)
     names = [e[0] for e in sim]
     buckets = 3                                     # one per scheme
-    for name in ("sim.upload", "sim.dispatch", "sim.fetch", "sim.assemble"):
+    for name in ("sim.dispatch", "sim.fetch", "sim.assemble"):
         assert names.count(name) == buckets
     assert "sim.plan" in names and "sim.runner_build" not in names
     rounds = [len(r.round_times) for r in res[:, 0, 0]]
     assert top[3]["lane_rounds"] == 3 * sum(rounds)
-    up = sum(e[3]["bytes"] for e in sim if e[0] == "sim.upload")
-    assert up == sum(_traces()[:, :r].nbytes for r in rounds)
+    # every bucket reads one copy of the whole trace set
+    (up,) = [e for e in sim if e[0] == "sim.upload"]
+    assert up[3]["bytes"] == _traces().nbytes
+    assert up[3]["buckets"] == buckets
     # each fetch brings at least the f64 round times of its bucket
     fetched = sum(e[3]["bytes"] for e in sim if e[0] == "sim.fetch")
     assert fetched >= 3 * sum(rounds) * 8
@@ -175,12 +177,14 @@ class _NoSpan:
 
 def _plain(monkeypatch):
     """The code path with no spans, no named scopes and the implicit
-    upload inside the runner call."""
+    upload (of the host array's slice) inside the runner call."""
     monkeypatch.setattr(tracing, "span",
                         lambda name, **attrs: contextlib.nullcontext(
                             _NoSpan()))
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(batch, "_upload",
+                        lambda traces, call, buckets=1: traces)
     monkeypatch.setattr(batch, "_staged_call",
                         lambda run, traces, call: jax.device_get(run(traces)))
     clear_runner_cache()
