@@ -504,13 +504,11 @@ def bench_grid_jax(num_specs: int = 64, num_traces: int = 8,
 
     Gates: (1) ONE compilation per shape bucket, verified via the
     runner-cache compile counter (the sweep folds into a single bucket,
-    so exactly one vmapped scan is built and jitted); (2) >= 3x
-    end-to-end — compiles included, how a fresh sweep actually pays —
-    over the per-spec cached-runner path (``fuse=False``), which
-    compiles one scan per spec; (3) grid-fused outputs exact on the
-    bool/int bookkeeping and allclose on floats vs the numpy oracle.
-    The ``grid-jax-smoke`` variant shrinks the sweep for tier-1 CI and
-    skips the timing gate (compile-count + parity only).
+    so exactly one vmapped scan is built and jitted); (2) grid-fused
+    outputs exact on the bool/int bookkeeping and allclose on floats vs
+    the numpy oracle.  The cold (compiling) and warm sweep times are
+    printed, not gated.  The ``grid-jax-smoke`` variant shrinks the
+    sweep for tier-1 CI and skips the warm timing.
     """
     from repro.core import (
         cache_stats,
@@ -536,7 +534,7 @@ def bench_grid_jax(num_specs: int = 64, num_traces: int = 8,
     clear_runner_cache()
     t0 = time.perf_counter()
     fused = simulate_batch(specs, traces, mu=MU, alpha=alpha,
-                           backend="jax", fuse=True)
+                           backend="jax")
     t_fused_e2e = time.perf_counter() - t0
     compiles = cache_stats()["compiles"]
     print(f"gridjax.compiles,{compiles},acceptance == {buckets} "
@@ -564,35 +562,10 @@ def bench_grid_jax(num_specs: int = 64, num_traces: int = 8,
     t_fused = float("inf")
     for _ in range(repeats):
         t0 = time.perf_counter()
-        simulate_batch(specs, traces, mu=MU, alpha=alpha,
-                       backend="jax", fuse=True)
+        simulate_batch(specs, traces, mu=MU, alpha=alpha, backend="jax")
         t_fused = min(t_fused, time.perf_counter() - t0)
-
-    # per-spec cached-runner path: one compile per spec end-to-end
-    clear_runner_cache()
-    t0 = time.perf_counter()
-    simulate_batch(specs, traces, mu=MU, alpha=alpha,
-                   backend="jax", fuse=False)
-    t_spec_e2e = time.perf_counter() - t0
-    spec_compiles = cache_stats()["compiles"]
-    t_spec = float("inf")
-    for _ in range(2):
-        t0 = time.perf_counter()
-        simulate_batch(specs, traces, mu=MU, alpha=alpha,
-                       backend="jax", fuse=False)
-        t_spec = min(t_spec, time.perf_counter() - t0)
-
-    speedup = t_spec_e2e / t_fused_e2e
     print(f"gridjax.fused_e2e_s,{t_fused_e2e:.3f},1 compile + sweep")
-    print(f"gridjax.perspec_e2e_s,{t_spec_e2e:.3f},{spec_compiles} compiles "
-          "+ sweep")
     print(f"gridjax.fused_steady_s,{t_fused:.3f},cache warm")
-    print(f"gridjax.perspec_steady_s,{t_spec:.3f},cache warm")
-    print(f"gridjax.steady_speedup,{t_spec / t_fused:.2f},informational")
-    print(f"gridjax.e2e_speedup,{speedup:.2f},acceptance >= 3x")
-    assert speedup >= 3.0, (
-        f"grid fusion only {speedup:.2f}x the per-spec runners end-to-end"
-    )
 
 
 def bench_batch_montecarlo():

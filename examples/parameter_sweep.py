@@ -8,15 +8,14 @@ Monte-Carlo version of the paper's App.-J probe procedure (what
 Table 1 / Figs. 15-18 aggregate).
 
     PYTHONPATH=src python examples/parameter_sweep.py [n] [rounds] \
-        [--backend jax] [--fuse | --no-fuse]
+        [--backend jax]
 
 ``--backend jax`` runs on the device-resident lockstep path (see
-docs/scheme_kernels.md, "Running on jax").  Grid fusion is ON by
-default there: the planner buckets specs by static shape key and each
-bucket compiles as ONE vmapped ``lax.scan`` — the per-scheme lines
-below report how many shape buckets each sweep folded into and how
-many runners were actually compiled, so the win over ``--no-fuse``
-(one compilation per spec) is visible directly.
+docs/scheme_kernels.md, "Running on jax"): the planner buckets specs
+by static shape key and each bucket compiles as ONE vmapped
+``lax.scan`` — the per-scheme lines below report how many shape
+buckets each sweep folded into and how many runners were actually
+compiled.
 """
 
 import sys
@@ -39,30 +38,19 @@ backend = None
 if "--backend" in args:
     i = args.index("--backend")
     if i + 1 >= len(args):
-        sys.exit("usage: parameter_sweep.py [n] [rounds] [--backend NAME] "
-                 "[--fuse | --no-fuse]")
+        sys.exit("usage: parameter_sweep.py [n] [rounds] [--backend NAME]")
     backend = args[i + 1]
     del args[i : i + 2]
     if backend not in available_backends():
         sys.exit(f"backend {backend!r} unavailable; have "
                  f"{available_backends()}")
-fuse = None
-if "--fuse" in args:
-    fuse = True
-    args.remove("--fuse")
-if "--no-fuse" in args:
-    fuse = False
-    args.remove("--no-fuse")
 n = int(args[0]) if len(args) > 0 else 64
 rounds = int(args[1]) if len(args) > 1 else 60
 
-from repro.core.batch import _fuse_enabled  # noqa: E402
-
 eff_backend = backend or get_backend().name
-fusing = eff_backend == "jax" and _fuse_enabled(fuse)
 print(f"kernel backend: {eff_backend} "
       f"(array namespace {get_backend(eff_backend).xp.__name__}, "
-      f"grid fusion {'on' if fusing else 'off'})")
+      f"grid fusion {'on' if eff_backend == 'jax' else 'off'})")
 
 # several independent GE traces of the Fig.-1-calibrated cluster
 # (traces are the Monte-Carlo axis: load-only sim results are
@@ -88,7 +76,7 @@ t0 = time.perf_counter()
 for scheme, specs in grids.items():
     compiles0 = cache_stats()["compiles"]
     results = simulate_batch(specs, traces, alpha=alpha, strict=False,
-                             backend=backend, fuse=fuse)
+                             backend=backend)
     compiled = cache_stats()["compiles"] - compiles0
     best_params, best_t = None, float("inf")
     for i, (_, params) in enumerate(specs):
@@ -107,7 +95,7 @@ for scheme, specs in grids.items():
                        reverse=True)
         print(f"         {len(specs)} specs -> {len(plan['buckets'])} "
               f"shape buckets {sizes} "
-              f"(+{len(plan['fallback'])} per-spec fallbacks, "
+              f"(+{len(plan['fallback'])} on the numpy engine, "
               f"{len(plan['infeasible'])} infeasible), "
               f"{compiled} runner compile(s) this sweep")
 elapsed = time.perf_counter() - t0

@@ -40,7 +40,6 @@ from .batch import (
     cache_stats,
     clear_runner_cache,
     grid_plan,
-    precompute_rounds,
     select_parameters_fast,
     simulate_batch,
     simulate_fast,
@@ -154,7 +153,6 @@ __all__ = [
     "simulate_batch",
     "simulate_lockstep",
     "select_parameters_fast",
-    "precompute_rounds",
     "grid_plan",
     "cache_stats",
     "clear_runner_cache",

@@ -20,23 +20,26 @@ This module batches that work at two levels:
   axis).  The per-round Python overhead is paid once per *grid*
   instead of once per *cell*, and the results stay bit-identical to
   per-cell ``simulate_fast`` runs (``tests/test_lockstep.py``;
-  speedup gate in ``benchmarks/run.py lockstep``).
+  speedup gate in ``benchmarks/run.py lockstep``).  On the jax backend
+  the spec runs as a one-member bucket of the staged engine below.
 * ``simulate_batch`` — runs a (specs x seeds x traces) grid.  On the
   jax backend the grid is **grid-fused**: specs are bucketed by static
   shape key (:func:`grid_plan`), scalar parameters are stacked into
   spec-axis arrays, and each bucket runs as ONE ``vmap``-wrapped
   jitted ``lax.scan`` — a whole parameter sweep pays one compilation
   per shape bucket.  Elsewhere (and for unstageable specs) it runs one
-  lockstep batch per spec.  Schemes whose load-only stepping ignores
-  the coefficient seed (``seed_sensitive = False``, all paper schemes)
-  run the trace axis ONCE and broadcast the results across the seed
-  axis.
+  numpy lockstep batch per spec.  Schemes whose load-only stepping
+  ignores the coefficient seed (``seed_sensitive = False``, all paper
+  schemes) run the trace axis ONCE and broadcast the results across
+  the seed axis.
 * ``select_parameters_fast`` — the App.-J probe sweep on top of
   ``simulate_batch``; ``simulator.select_parameters`` delegates here.
 
-Every floating-point expression mirrors the legacy code exactly (same
-ops, same order), so results are reproducible to the bit, not just to a
-tolerance.
+Every engine takes the round rule (mu-rule, wait-out, duration) from
+``core.simulator`` (:func:`~repro.core.simulator.round_cutoff`,
+:func:`~repro.core.simulator.round_duration`,
+:class:`~repro.core.simulator.RoundGate`), so the numpy results are
+reproducible to the bit, not just to a tolerance.
 """
 
 from __future__ import annotations
@@ -62,15 +65,16 @@ from .kernel import (
 from .schemes import Scheme, make_scheme
 from .simulator import (
     Candidate,
+    RoundGate,
     SimResult,
     default_grid,
     estimate_alpha,
+    round_cutoff,
+    round_duration,
+    round_duration_all,
 )
-from .straggler import ConformanceGate
 
 __all__ = [
-    "RoundPrecompute",
-    "precompute_rounds",
     "simulate_fast",
     "simulate_lockstep",
     "simulate_batch",
@@ -81,42 +85,6 @@ __all__ = [
 ]
 
 
-@dataclass(frozen=True)
-class RoundPrecompute:
-    """Per-round timing quantities for one (trace, load) pair.
-
-    ``times[t]`` are the load-adjusted worker seconds of round t+1;
-    ``cand[t]`` is the mu-rule candidate straggler mask *before* the
-    wait-out gate.  Rows beyond a scheme's horizon are simply unused, so
-    one precompute serves schemes with different T.
-    """
-
-    times: np.ndarray    # (rounds, n) float
-    kappa: np.ndarray    # (rounds,)  fastest worker per round
-    cutoff: np.ndarray   # (rounds,)  (1 + mu) * kappa
-    tmax: np.ndarray     # (rounds,)  slowest worker per round
-    cand: np.ndarray     # (rounds, n) bool
-    any_cand: np.ndarray  # (rounds,) bool
-
-
-def precompute_rounds(
-    ref_delays: np.ndarray, extra: float, mu: float
-) -> RoundPrecompute:
-    """Vectorize the per-round timing math of ``simulate`` over rounds."""
-    times = ref_delays + extra
-    kappa = times.min(axis=1)
-    cutoff = (1.0 + mu) * kappa
-    cand = times > cutoff[:, None]
-    return RoundPrecompute(
-        times=times,
-        kappa=kappa,
-        cutoff=cutoff,
-        tmax=times.max(axis=1),
-        cand=cand,
-        any_cand=cand.any(axis=1),
-    )
-
-
 def simulate_fast(
     scheme: Scheme,
     ref_delays: np.ndarray,
@@ -125,13 +93,10 @@ def simulate_fast(
     alpha: float = 1.0,
     J: int | None = None,
     waitout: str = "selective",
-    pre: RoundPrecompute | None = None,
 ) -> SimResult:
     """Load-only fast simulation: bit-for-bit the same ``SimResult`` as
     the legacy ``simulate`` without MiniTask materialization or decode
-    solves.  ``pre`` lets grid sweeps share the vectorized per-round
-    precompute across candidates with the same (trace, load).
-    """
+    solves."""
     n = scheme.n
     J = J if J is not None else scheme.J
     rounds = J + scheme.T
@@ -140,10 +105,7 @@ def simulate_fast(
             f"need delays of shape (>={rounds}, {n}), got {ref_delays.shape}"
         )
     extra = (scheme.normalized_load - 1.0 / n) * alpha
-    if pre is None:
-        pre = precompute_rounds(ref_delays[:rounds], extra, mu)
-
-    gate = ConformanceGate(scheme.design_model, n)
+    rg = RoundGate(scheme.design_model, n, mu=mu, waitout=waitout)
     round_times = np.zeros(rounds)
     job_done_round: dict[int, int] = {}
     job_done_time: dict[int, float] = {}
@@ -151,29 +113,8 @@ def simulate_fast(
 
     for t in range(1, rounds + 1):
         k = t - 1
-        times = pre.times[k]
-        cutoff = pre.cutoff[k]
-        tmax = pre.tmax[k]
-        if not pre.any_cand[k]:
-            candidate = pre.cand[k]
-            gate.force(candidate)
-            duration = float(min(cutoff, tmax))
-        elif waitout == "selective":
-            candidate, waited = gate.admit_partial(pre.cand[k], times)
-            if waited:
-                waitouts += 1
-                duration = float(max(times[waited].max(), min(cutoff, tmax) if candidate.any() else cutoff))
-            else:
-                duration = float(min(cutoff, tmax))
-        else:  # App-J fallback: wait out all workers on violation
-            if gate.admit(pre.cand[k]):
-                candidate = pre.cand[k]
-                duration = float(min(cutoff, tmax))
-            else:
-                waitouts += 1
-                candidate = np.zeros(n, dtype=bool)
-                gate.force(candidate)
-                duration = float(tmax)
+        candidate, duration, waited = rg.round(ref_delays[k] + extra)
+        waitouts += waited
         scheme.step(t, candidate)
         round_times[k] = duration
         done = scheme.collect_jobs(t)
@@ -197,7 +138,7 @@ def simulate_fast(
         job_done_round=job_done_round,
         job_done_time=job_done_time,
         waitouts=waitouts,
-        effective_pattern=gate.history,
+        effective_pattern=rg.history,
         normalized_load=scheme.normalized_load,
     )
 
@@ -214,7 +155,6 @@ def simulate_lockstep(
     seed: int = 0,
     strict: bool = True,
     backend: str | None = None,
-    call: int | None = None,
 ) -> list[SimResult | None]:
     """Advance one spec through MANY traces in lockstep.
 
@@ -223,22 +163,23 @@ def simulate_lockstep(
     cells axis, so each round of the whole grid is a handful of array
     ops.  On the default **numpy** backend every per-cell ``SimResult``
     is bit-identical to the scalar ``simulate_fast`` run on that trace
-    (and hence to the legacy ``simulate``): the timing math, gate
-    decisions, and elapsed-time accounting replicate the scalar
-    expressions exactly.
+    (and hence to the legacy ``simulate``): both take the round rule
+    from ``core.simulator`` and the elapsed-time accounting replicates
+    the scalar sums exactly.
 
     With ``backend="jax"`` (or when jax is the process default, e.g.
-    ``REPRO_BACKEND=jax``) the whole (cells x rounds) sweep is staged
-    as ONE jitted ``lax.scan`` per spec: the per-round transition —
-    gate admission plus ``kernel.step`` — is a pure
-    ``(state, (t, stragglers)) -> (state, outputs)`` function carried
-    over the rounds axis, and results transfer to the host once.  The
-    jax path is an "allclose" contract against the numpy oracle: exact
-    on the bool/int bookkeeping (done rounds, dead flags, gate
-    patterns, waitouts), allclose on float loads/runtimes.  Specs the
-    staged path cannot express (load-adaptive ``round_loads``
-    overrides, gate members without analytic wait-out solvers) fall
-    back to this numpy engine transparently.
+    ``REPRO_BACKEND=jax``) the spec runs as a one-member bucket of the
+    staged engine that ``simulate_batch`` uses: the whole (cells x
+    rounds) sweep is ONE jitted, ``vmap``-wrapped ``lax.scan`` whose
+    per-round transition — gate admission plus ``kernel.step`` — is a
+    pure ``(state, (t, stragglers)) -> (state, outputs)`` function
+    carried over the rounds axis, and results transfer to the host
+    once.  The jax path is an "allclose" contract against the numpy
+    oracle: exact on the bool/int bookkeeping (done rounds, dead flags,
+    gate patterns, waitouts), allclose on float loads/runtimes.  Specs
+    the staged path cannot express (load-adaptive ``round_loads``
+    overrides, gate members without analytic wait-out solvers) run on
+    the numpy engine.
 
     ``traces``: (cells, rounds, n).  ``J = None`` fits ``J + T`` inside
     the trace (the App-J rule).  With ``strict=False``, cells whose
@@ -249,10 +190,7 @@ def simulate_lockstep(
     worker i's round time is ``trace + (load_i - 1/n) * alpha[i]``,
     with the per-cell loads still coming from the kernel's
     ``round_loads`` protocol.  Identical broadcasting on every path
-    (scalar, numpy lockstep, jax scan, fused grid).
-
-    ``call`` is the span identifier of an enclosing ``simulate_batch``
-    call; a fresh one otherwise.
+    (scalar, numpy lockstep, staged scan).
     """
     traces = np.asarray(traces, dtype=np.float64)
     if traces.ndim == 2:
@@ -278,14 +216,15 @@ def simulate_lockstep(
         )
     bk_name = backend if backend is not None else get_backend().name
     if bk_name == "jax":
-        res = _simulate_lockstep_jax(
-            name, params, scheme, traces, mu=mu, alpha=alpha, J=J,
-            waitout=waitout, seed=seed, strict=strict, call=call,
-        )
-        if res is not None:
-            return res
+        out = np.empty((1, 1, cells), dtype=object)
+        entry = _RunEntry(0, 0, name, dict(params), J, seed)
+        if not _simulate_batch_fused(
+            [entry], traces, out, mu=mu, alpha=alpha, waitout=waitout,
+            strict=strict, call=tracing.next_call_id(),
+        ):
+            return list(out[0, 0])
 
-    # numpy engine — the bit-for-bit oracle (and the fallback for specs
+    # numpy engine — the bit-for-bit oracle (and the engine for specs
     # the staged path cannot express); kernels pinned to the numpy
     # backend regardless of the process default
     nbk = get_backend("numpy")
@@ -307,45 +246,32 @@ def simulate_lockstep(
     if const_load:
         extra_s = (kernel.normalized_load - inv_n) * alpha
         times_all = traces[:, :rounds, :] + extra_s
-        kappa_all = times_all.min(axis=2)
-        cutoff_all = (1.0 + mu) * kappa_all
-        tmax_all = times_all.max(axis=2)
-        cand_all = times_all > cutoff_all[..., None]
-        any_all = cand_all.any(axis=2)
+        rule_all = round_cutoff(times_all, mu)
 
     for t in range(1, rounds + 1):
         k = t - 1
-        # per-round timing math (identical expressions to simulate_fast,
-        # broadcast over cells; loads come from the kernel so
-        # load-adaptive schemes can vary them per cell / per round)
+        # per-round timing (loads come from the kernel so load-adaptive
+        # schemes can vary them per cell / per round)
         if const_load:
-            times, kappa, cutoff = times_all[:, k], kappa_all[:, k], cutoff_all[:, k]
-            tmax, cand, any_cand = tmax_all[:, k], cand_all[:, k], any_all[:, k]
+            times = times_all[:, k]
+            cutoff, tmax, base, cand, any_cand = (a[:, k] for a in rule_all)
         else:
             # (cells, 1) loads x scalar-or-(n,) alpha: heterogeneous
             # per-worker load slopes broadcast into a (cells, n) extra
             extra = (kernel.round_loads(state, t) - inv_n)[:, None] * alpha
             times = traces[:, k, :] + extra
-            kappa = times.min(axis=1)
-            cutoff = (1.0 + mu) * kappa
-            tmax = times.max(axis=1)
-            cand = times > cutoff[:, None]
-            any_cand = cand.any(axis=1)
-        base = np.minimum(cutoff, tmax)
+            cutoff, tmax, base, cand, any_cand = round_cutoff(times, mu)
         if waitout == "selective":
             gs, eff, waited = gate.admit_partial(gs, cand, times, any_cand)
-            waited_any = waited.any(axis=1)
-            wmax = np.where(waited, times, -np.inf).max(axis=1)
-            dur_w = np.maximum(
-                wmax, np.where(eff.any(axis=1), base, cutoff)
+            duration, wo = round_duration(
+                times, cutoff, base, eff, waited, np
             )
-            duration = np.where(waited_any, dur_w, base)
-            waitouts += waited_any
         else:  # App-J fallback: wait out all workers on violation
             gs, eff, ok_any = gate.admit_all(gs, cand, any_cand)
-            wo = any_cand & ~ok_any
-            duration = np.where(wo, tmax, base)
-            waitouts += wo
+            duration, wo = round_duration_all(
+                tmax, base, any_cand, ok_any, np
+            )
+        waitouts += wo
         state = kernel.step(state, t, eff)
         rt[:, k] = duration
         # elapsed time for jobs that completed this round; the row-wise
@@ -450,34 +376,14 @@ def _assemble_results(
     return results
 
 
-# staged-scan runners: per-SPEC runners (one jitted scan per
-# (scheme, params, n, J, waitout[, seed]) spec, ``simulate_lockstep``)
-# and per-BUCKET grid runners (one vmapped scan per shape bucket of a
-# fused ``simulate_batch`` sweep) share one FIFO cache, so
-# recompilation is paid once per spec / bucket, not once per call (the
-# ``lockstep-jax`` and ``grid-jax`` benches gate this).  The seed
-# enters keys only for seed-sensitive schemes — load-only stepping
-# never reads the code coefficients otherwise.  The registered
-# factory/kernel OBJECTS are part of every key (hashed by identity,
-# and the key reference keeps them alive so a freed address can never
-# be recycled into a colliding id), so re-registering a scheme or
-# kernel — the extension API's register/unregister pattern — never
-# hits a stale compiled runner or a stale "unsupported" verdict; the
-# FIFO cap (``REPRO_RUNNER_CACHE_CAP``, default 256) keeps long
-# parameter sweeps from holding every compiled executable for the
+# staged-scan runners: one vmapped scan per shape bucket
+# (:func:`_bucket_key`), held in one FIFO cache, so recompilation is
+# paid once per bucket, not once per call (the ``grid-jax`` bench gates
+# this).  The FIFO cap (``REPRO_RUNNER_CACHE_CAP``, default 256) keeps
+# long parameter sweeps from holding every compiled executable for the
 # process lifetime.
 _JAX_RUNNERS: dict[tuple, object] = {}
 _RUNNER_CACHE_CAP_DEFAULT = 256
-_JAX_UNSUPPORTED = object()
-#: "unsupported spec" verdicts live in a SIDE table: they are cheap
-#: host-side markers, so they must neither count toward the FIFO cap
-#: nor push hot *compiled* runners out of ``_JAX_RUNNERS`` (long mixed
-#: sweeps interleave many unstageable specs with a few compiled ones).
-#: Still FIFO-bounded (generously — re-deriving an evicted verdict is
-#: cheap, no compile) so unbounded spec churn in a long-lived process
-#: cannot grow memory without limit.
-_JAX_UNSUPPORTED_VERDICTS: dict[tuple, object] = {}
-_VERDICT_CACHE_CAP = 4096
 _CACHE_COUNTERS = {"hits": 0, "misses": 0, "evictions": 0, "compiles": 0}
 
 
@@ -500,35 +406,23 @@ def _runner_cache_cap() -> int:
 
 def cache_stats() -> dict:
     """Counters for the compiled-runner cache: ``hits`` / ``misses`` /
-    ``evictions`` plus ``compiles`` (cache misses that actually built
-    and staged a runner — "unsupported spec" verdicts are misses but
-    not compiles), the current ``size`` / ``cap`` of the compiled-
-    runner FIFO, and ``unsupported`` — the cached verdict count, held
-    in a side table exempt from the cap.  The ``grid-jax`` bench
+    ``evictions`` plus ``compiles`` (runners built and staged), and the
+    current ``size`` / ``cap`` of the FIFO.  The ``grid-jax`` bench
     asserts one compile per shape bucket off these."""
     return dict(_CACHE_COUNTERS, size=len(_JAX_RUNNERS),
-                cap=_runner_cache_cap(),
-                unsupported=len(_JAX_UNSUPPORTED_VERDICTS))
+                cap=_runner_cache_cap())
 
 
 def clear_runner_cache() -> None:
-    """Drop every cached runner and verdict and zero the
-    :func:`cache_stats` counters (benchmarks use this to measure
-    cold-start compiles)."""
+    """Drop every cached runner and zero the :func:`cache_stats`
+    counters (benchmarks use this to measure cold-start compiles)."""
     _JAX_RUNNERS.clear()
-    _JAX_UNSUPPORTED_VERDICTS.clear()
     for k in _CACHE_COUNTERS:
         _CACHE_COUNTERS[k] = 0
 
 
 def _runner_cache_lookup(key: tuple, build):
-    """FIFO-cached runner lookup; ``build()`` runs on a miss and may
-    return ``_JAX_UNSUPPORTED`` (cached too — in the cap-exempt side
-    table, so the verdict is neither re-derived every call nor able to
-    evict a hot compiled runner)."""
-    if key in _JAX_UNSUPPORTED_VERDICTS:
-        _CACHE_COUNTERS["hits"] += 1
-        return _JAX_UNSUPPORTED
+    """FIFO-cached runner lookup; ``build()`` runs on a miss."""
     entry = _JAX_RUNNERS.get(key)
     if entry is not None:
         _CACHE_COUNTERS["hits"] += 1
@@ -536,13 +430,6 @@ def _runner_cache_lookup(key: tuple, build):
     _CACHE_COUNTERS["misses"] += 1
     with tracing.span("sim.runner_build"):
         entry = build()
-    if entry is _JAX_UNSUPPORTED:
-        while len(_JAX_UNSUPPORTED_VERDICTS) >= _VERDICT_CACHE_CAP:
-            _JAX_UNSUPPORTED_VERDICTS.pop(
-                next(iter(_JAX_UNSUPPORTED_VERDICTS))
-            )
-        _JAX_UNSUPPORTED_VERDICTS[key] = entry
-        return entry
     _CACHE_COUNTERS["compiles"] += 1
     cap = _runner_cache_cap()
     while len(_JAX_RUNNERS) >= cap:
@@ -552,52 +439,27 @@ def _runner_cache_lookup(key: tuple, build):
     return entry
 
 
-def _jax_runner_key(scheme, params: dict, J: int, waitout: str, seed: int):
-    from .kernel import _KERNELS
-    from .schemes import _SCHEME_FACTORIES
-
-    sensitive = (
-        getattr(scheme, "seed_sensitive", False)
-        or kernel_seed_sensitive(scheme.name)
-    )
-    return (
-        "spec",
-        scheme.name,
-        _SCHEME_FACTORIES.get(scheme.name),
-        _KERNELS.get(scheme.name),
-        tuple(sorted((str(k), v) for k, v in params.items())),
-        scheme.n,
-        J,
-        waitout,
-        seed if sensitive else None,
-    )
-
-
-def _stageable(kernel_or_none, gate_or_none, waitout: str) -> bool:
-    """Can the static-shape scan path express this spec?  Shared by the
-    per-spec runner builder and the grid-fusion planner (which must
-    route unstageable specs to the per-spec fallback BEFORE bucketing).
-    False when: no registered kernel, load-adaptive ``round_loads``
-    overrides (the timing precompute assumes one constant load), or —
-    in selective wait-out — gate members without the analytic
-    ``min_drops_batch`` solver.  Callers pass the gate they already
-    built for the spec (None only alongside a None kernel)."""
-    if kernel_or_none is None:
-        return False
-    if type(kernel_or_none).round_loads is not SchemeKernel.round_loads:
+def _stageable(kernel, gate, waitout: str) -> bool:
+    """Can the static-shape scan path express this spec?  The planner
+    routes unstageable specs (and kernel-less schemes) to the numpy
+    engine BEFORE bucketing.  False when: load-adaptive
+    ``round_loads`` overrides (the timing precompute assumes one
+    constant load), or — in selective wait-out — gate members without
+    the analytic ``min_drops_batch`` solver.  ``gate`` is the spec's
+    gate, needed only in selective wait-out."""
+    if type(kernel).round_loads is not SchemeKernel.round_loads:
         return False
     if waitout == "selective":
-        return gate_or_none.analytic
+        return gate.analytic
     return True
 
 
 def _staged_lockstep_run(kernel, gate, rounds: int, selective: bool,
                          traces_dev, mu, alpha, load):
     """One spec's whole (cells x rounds) lockstep sweep as a ``scan``
-    over the rounds axis — the pure traced core shared by the per-spec
-    jitted runner and the grid-fused (vmapped) bucket runner.  ``mu``,
-    ``alpha`` and ``load`` are traced scalars (per-spec lanes of the
-    stacked arrays under ``vmap``)."""
+    over the rounds axis — the pure traced core of the (vmapped) bucket
+    runner.  ``mu``, ``alpha`` and ``load`` are traced scalars (per-spec
+    lanes of the stacked arrays under ``vmap``)."""
     import jax
     import jax.numpy as jnp
 
@@ -613,14 +475,9 @@ def _staged_lockstep_run(kernel, gate, rounds: int, selective: bool,
         flat, bufs, alive = carry
         t, times = xs
         state = state_unflatten(cls, list(flat))
-        # identical expressions to the numpy engine, one round at
-        # a time under the scan
-        kappa = times.min(axis=1)
-        cutoff = (1.0 + mu) * kappa
-        tmax = times.max(axis=1)
-        cand = times > cutoff[:, None]
-        any_cand = cand.any(axis=1)
-        base = jnp.minimum(cutoff, tmax)
+        # the numpy engine's round rule, one round at a time under the
+        # scan
+        cutoff, tmax, base, cand, any_cand = round_cutoff(times, mu)
         gs = GateState(bufs=list(bufs), alive=alive,
                        filled=gate.full, history=None)
         if selective:
@@ -628,18 +485,15 @@ def _staged_lockstep_run(kernel, gate, rounds: int, selective: bool,
                 gs, eff, waited = gate.admit_partial(
                     gs, cand, times, any_cand
                 )
-            waited_any = waited.any(axis=1)
-            wmax = jnp.where(waited, times, -jnp.inf).max(axis=1)
-            dur_w = jnp.maximum(
-                wmax, jnp.where(eff.any(axis=1), base, cutoff)
+            duration, wflag = round_duration(
+                times, cutoff, base, eff, waited, jnp
             )
-            duration = jnp.where(waited_any, dur_w, base)
-            wflag = waited_any
         else:
             with jax.named_scope("gate"):
                 gs, eff, ok_any = gate.admit_all(gs, cand, any_cand)
-            wflag = any_cand & ~ok_any
-            duration = jnp.where(wflag, tmax, base)
+            duration, wflag = round_duration_all(
+                tmax, base, any_cand, ok_any, jnp
+            )
         with jax.named_scope("scheme_step"):
             state = kernel.step(state, t, eff)
         _, flat = state_flatten(state)
@@ -667,34 +521,6 @@ def _staged_lockstep_run(kernel, gate, rounds: int, selective: bool,
     )
 
 
-def _build_jax_runner(scheme, J: int, waitout: str):
-    """Stage one spec's whole lockstep sweep as a jitted ``lax.scan``.
-
-    Returns ``_JAX_UNSUPPORTED`` for specs the static-shape path cannot
-    express (see :func:`_stageable`).
-    """
-    bkj = get_backend("jax")
-    try:
-        kernel = make_kernel(scheme, bkj)
-    except KeyError:
-        kernel = None
-    gate = (
-        GateKernel(scheme.design_model, scheme.n, bkj)
-        if kernel is not None else None
-    )
-    if not _stageable(kernel, gate, waitout):
-        return _JAX_UNSUPPORTED
-    rounds = J + kernel.T
-    selective = waitout == "selective"
-
-    def run(traces_dev, mu, alpha, load):
-        return _staged_lockstep_run(
-            kernel, gate, rounds, selective, traces_dev, mu, alpha, load
-        )
-
-    return bkj.jit(run), kernel.name
-
-
 def _build_jax_grid_runner(scheme, J: int, waitout: str,
                            fused_names: tuple):
     """Stage one shape BUCKET — many specs sharing every static shape —
@@ -706,19 +532,13 @@ def _build_jax_grid_runner(scheme, J: int, waitout: str,
     representative kernel / design model (``SchemeKernel.bind_fused``),
     so the whole bucket compiles ONCE and transfers to the host once.
     The traces are shared across lanes (``in_axes=None``) — every spec
-    of a ``simulate_batch`` call replays the same trace set.
+    of a ``simulate_batch`` call replays the same trace set.  The
+    planner has already checked that the spec is stageable.
     """
     bkj = get_backend("jax")
-    try:
-        kernel0 = make_kernel(scheme, bkj)
-    except KeyError:
-        kernel0 = None
-    gate0 = (
-        GateKernel(scheme.design_model, scheme.n, bkj)
-        if kernel0 is not None else None
-    )
-    if not _stageable(kernel0, gate0, waitout):
-        return _JAX_UNSUPPORTED
+    kernel0 = make_kernel(scheme, bkj)
+    gate0 = GateKernel(scheme.design_model, scheme.n, bkj)
+    assert _stageable(kernel0, gate0, waitout), scheme.name
     rounds = J + kernel0.T
     selective = waitout == "selective"
     n = kernel0.n
@@ -767,63 +587,6 @@ def _staged_call(run, traces_dev, call: int) -> dict:
             sp.set_metadata(bytes=sum(int(np.asarray(x).nbytes)
                                       for x in jax.tree.leaves(host)))
     return host
-
-
-def _simulate_lockstep_jax(
-    name: str,
-    params: dict,
-    scheme,
-    traces: np.ndarray,
-    *,
-    mu: float,
-    alpha: float,
-    J: int,
-    waitout: str,
-    seed: int,
-    strict: bool,
-    call: int | None,
-) -> list[SimResult | None] | None:
-    """The device-resident lockstep path; ``None`` means "spec not
-    stageable, use the numpy engine".
-
-    Runs under a scoped ``jax.enable_x64`` so the float timing math is
-    f64 like the oracle — the bool/int bookkeeping then matches the
-    numpy engine exactly and loads/runtimes allclose (on CPU typically
-    bit-equal, but only allclose is contractual).
-    """
-    import jax
-
-    key = _jax_runner_key(scheme, params, J, waitout, seed)
-    with jax.enable_x64(True):
-        entry = _runner_cache_lookup(
-            key, lambda: _build_jax_runner(scheme, J, waitout)
-        )
-        if entry is _JAX_UNSUPPORTED:
-            return None
-        runner, kernel_name = entry
-        rounds = J + scheme.T
-        # alpha may be a per-worker (n,) vector (heterogeneous load
-        # slopes); a 0-d array otherwise — jit re-stages per shape
-        alpha_arr = np.asarray(alpha, dtype=np.float64)
-        if call is None:
-            call = tracing.next_call_id()
-        host = _staged_call(
-            lambda traces: runner(
-                traces, float(mu), alpha_arr,
-                float(scheme.normalized_load),
-            ),
-            _upload(traces[:, :rounds], call), call,
-        )
-    with tracing.span("sim.assemble", call=call, cells=len(traces)):
-        return _assemble_results(
-            kernel_name, scheme.normalized_load, J,
-            np.asarray(host["rt"], dtype=np.float64),
-            np.asarray(host["done_round"]),
-            np.asarray(host["dead"]),
-            np.asarray(host["waitouts"]),
-            np.asarray(host["history"]),
-            strict, None,
-        )
 
 
 @dataclass(frozen=True)
@@ -892,23 +655,53 @@ def _plan_entries(specs, traces, seeds, J, strict, out):
     return entries, sensitive_map
 
 
-def _plan_buckets(entries, traces_shape, waitout, strict, out):
-    """Group stageable run entries into shape buckets (the grid-fusion
-    planner).  Entries the fused path cannot express — kernel-less
-    schemes, load-adaptive loads, non-analytic gates — come back as
-    leftovers for the transparent per-spec fallback; entries whose
-    constructor rejects the fitted J mark their rows (strict raises).
-
-    The bucket key is the spec's full STATIC signature: scheme name +
-    registered factory/kernel identity, the non-fused ("structural")
-    parameters, n, J, T, waitout, the trace count, and — for
-    seed-sensitive schemes — the seed (mirroring the per-spec runner
-    cache).  The kernel's ``fused_params`` values are excluded: they
-    stack into per-bucket spec-axis arrays instead.
-    """
+def _bucket_key(scheme, kern, params: dict, J: int, waitout: str,
+                num_traces: int, seed: int) -> tuple:
+    """The runner-cache key of a stageable spec: its full STATIC
+    signature.  Scheme name + registered factory/kernel OBJECTS (hashed
+    by identity, and the key reference keeps them alive so a freed
+    address can never be recycled into a colliding id — re-registering
+    a scheme or kernel never hits a stale compiled runner), the
+    non-fused ("structural") parameters, n, J, T, waitout, the trace
+    count, and — for seed-sensitive schemes, whose load-only stepping
+    reads the code coefficients — the seed.  The kernel's
+    ``fused_params`` values are excluded: they stack into per-bucket
+    spec-axis arrays instead."""
     from .kernel import _KERNELS
     from .schemes import _SCHEME_FACTORIES
 
+    fused_names = tuple(kern.fused_params)
+    sensitive = (
+        getattr(scheme, "seed_sensitive", False)
+        or kernel_seed_sensitive(scheme.name)
+    )
+    structural = tuple(sorted(
+        (str(k), v) for k, v in params.items() if k not in fused_names
+    ))
+    return (
+        "grid",
+        scheme.name,
+        _SCHEME_FACTORIES.get(scheme.name),
+        _KERNELS.get(scheme.name),
+        structural,
+        fused_names,
+        scheme.n,
+        J,
+        kern.T,
+        waitout,
+        num_traces,
+        seed if sensitive else None,
+    )
+
+
+def _plan_buckets(entries, traces_shape, waitout, strict, out):
+    """Group stageable run entries into shape buckets (the grid-fusion
+    planner, keyed by :func:`_bucket_key`).  Entries the staged path
+    cannot express — kernel-less schemes, load-adaptive loads,
+    non-analytic gates — come back as leftovers for the numpy engine;
+    entries whose constructor rejects the fitted J mark their rows
+    (strict raises).
+    """
     num_traces, rounds_avail, n = traces_shape
     nbk = get_backend("numpy")
     leftover: list[_RunEntry] = []
@@ -937,33 +730,12 @@ def _plan_buckets(entries, traces_shape, waitout, strict, out):
         if not _stageable(kern, gate, waitout):
             leftover.append(e)
             continue
-        fused_names = tuple(kern.fused_params)
-        sensitive = (
-            getattr(scheme, "seed_sensitive", False)
-            or kernel_seed_sensitive(scheme.name)
-        )
-        structural = tuple(sorted(
-            (str(k), v) for k, v in e.params.items()
-            if k not in fused_names
-        ))
-        key = (
-            "grid",
-            scheme.name,
-            _SCHEME_FACTORIES.get(scheme.name),
-            _KERNELS.get(scheme.name),
-            structural,
-            fused_names,
-            n,
-            e.J,
-            kern.T,
-            waitout,
-            num_traces,
-            e.seed if sensitive else None,
-        )
+        key = _bucket_key(scheme, kern, e.params, e.J, waitout,
+                          num_traces, e.seed)
         bucket = buckets.get(key)
         if bucket is None:
             bucket = buckets[key] = _Bucket(
-                key, e.J, kern.T, fused_names, scheme
+                key, e.J, kern.T, tuple(kern.fused_params), scheme
             )
         bucket.members.append((e, scheme, kern.fused_scalars(scheme)))
     return leftover, list(buckets.values())
@@ -976,8 +748,8 @@ def _simulate_batch_fused(entries, traces, out, *, mu, alpha, waitout,
     shape bucket.  Every bucket replays the same traces, so they cross
     to the device once a call; a bucket whose J+T falls short of the
     trace length reads a device slice of its rounds, so its program
-    takes only its own J+T.  Returns the entries left for the per-spec
-    path."""
+    takes only its own J+T.  Returns the entries left for the numpy
+    engine."""
     import jax
     import jax.numpy as jnp
 
@@ -991,16 +763,12 @@ def _simulate_batch_fused(entries, traces, out, *, mu, alpha, waitout,
     with jax.enable_x64(True):
         traces_dev = _upload(traces, call, buckets=len(buckets))
         for b in buckets:
-            entry = _runner_cache_lookup(
+            runner, kernel_name = _runner_cache_lookup(
                 b.key,
                 lambda b=b: _build_jax_grid_runner(
                     b.scheme0, b.J, waitout, b.fused_names
                 ),
             )
-            if entry is _JAX_UNSUPPORTED:  # pragma: no cover - planner
-                leftover.extend(e for e, _, _ in b.members)  # pre-checks
-                continue
-            runner, kernel_name = entry
             rounds = b.J + b.T
             S = len(b.members)
             mu_s = jnp.full((S,), float(mu), dtype=jnp.float64)
@@ -1042,32 +810,6 @@ def _simulate_batch_fused(entries, traces, out, *, mu, alpha, waitout,
     return leftover
 
 
-_FUSE_OFF_VALUES = ("0", "false", "off", "no")
-_FUSE_ON_VALUES = ("", "1", "true", "on", "yes")
-
-
-def _fuse_enabled(fuse: bool | None) -> bool:
-    """Grid fusion defaults ON for the jax backend; disable per call
-    (``fuse=False``) or per process (``REPRO_GRID_FUSE=0``).  An
-    unrecognized env value warns (mirroring the
-    ``REPRO_RUNNER_CACHE_CAP`` parser) instead of silently acting as
-    fuse-ON — a typo like ``"nope"`` should not flip the engine's
-    execution strategy without a trace."""
-    if fuse is not None:
-        return fuse
-    raw = os.environ.get("REPRO_GRID_FUSE", "1").strip().lower()
-    if raw in _FUSE_OFF_VALUES:
-        return False
-    if raw not in _FUSE_ON_VALUES:
-        warnings.warn(
-            f"REPRO_GRID_FUSE={raw!r} is not a recognized on/off value "
-            f"(off: {'/'.join(_FUSE_OFF_VALUES)}; on: 1/true/on/yes); "
-            "grid fusion stays ON",
-            stacklevel=2,
-        )
-    return True
-
-
 def grid_plan(
     specs: list[tuple[str, dict]],
     traces: np.ndarray,
@@ -1083,7 +825,8 @@ def grid_plan(
     [...]}`` — every input spec index lands in exactly one of the
     three: a bucket dict (scheme name, member spec indices, the shared
     ``J``/``T``, the fused stacked-scalar parameter names), the
-    per-spec ``fallback`` list (stageability blockers), or
+    ``fallback`` list of specs the numpy engine runs (stageability
+    blockers), or
     ``infeasible`` (the constructor rejected the spec / grid outright
     — ``strict=False`` None rows).  Purely host-side — nothing is
     traced or compiled — so CLIs and benchmarks can report expected
@@ -1130,7 +873,7 @@ def simulate_batch(
     waitout: str = "selective",
     strict: bool = True,
     backend: str | None = None,
-    fuse: bool | None = None,
+    fuse: bool = True,
 ) -> np.ndarray:
     """Run a (specs x seeds x traces) grid on the lockstep engine.
 
@@ -1149,13 +892,15 @@ def simulate_batch(
     ONE ``vmap``-wrapped jitted ``lax.scan`` with a single device->host
     transfer — a whole parameter sweep pays one compilation per shape
     bucket instead of one per spec (``benchmarks/run.py grid-jax``
-    gates this).  ``fuse=False`` (or ``REPRO_GRID_FUSE=0``) restores
-    the per-spec runners; specs the fused path cannot stage fall back
-    to them transparently, with identical results either way (exact
-    bool/int bookkeeping, allclose floats — ``tests/test_grid_fused.py``).
+    gates this).  Specs the staged path cannot express run on the numpy
+    engine, with identical results either way (exact bool/int
+    bookkeeping, allclose floats — ``tests/test_grid_fused.py``).
+    ``fuse`` is accepted for callers that pass ``True``; there is no
+    other jax engine to turn to.
 
-    Otherwise each spec advances all of its traces in lockstep
-    (:func:`simulate_lockstep`); ragged grids are fine — every spec
+    Otherwise each spec advances all of its traces in lockstep on the
+    numpy engine (:func:`simulate_lockstep`); ragged grids are fine —
+    every spec
     gets its own ``J``/``T`` (the App-J fit-the-trace rule) and state
     shapes.  ``seeds`` vary only the schemes' gradient-code
     coefficients, which the load-only path never reads: for schemes
@@ -1172,6 +917,9 @@ def simulate_batch(
             f"unknown backend {backend!r}; available: "
             f"{available_backends()}"
         )
+    if not fuse:
+        raise ValueError("simulate_batch runs the staged buckets only; "
+                         "use backend='numpy' for the lockstep engine")
     traces = np.asarray(traces, dtype=np.float64)
     if traces.ndim == 2:
         traces = traces[None]
@@ -1185,7 +933,7 @@ def simulate_batch(
             )
         planned = entries
         bk_name = backend if backend is not None else get_backend().name
-        if bk_name == "jax" and _fuse_enabled(fuse):
+        if bk_name == "jax":
             entries = _simulate_batch_fused(
                 entries, traces, out, mu=mu, alpha=alpha, waitout=waitout,
                 strict=strict, call=call,
@@ -1200,7 +948,7 @@ def simulate_batch(
                     row = simulate_lockstep(
                         e.name, e.params, traces, mu=mu, alpha=alpha, J=e.J,
                         waitout=waitout, seed=e.seed, strict=strict,
-                        backend=backend, call=call,
+                        backend="numpy",
                     )
                 except ValueError:
                     if strict:

@@ -11,12 +11,19 @@ Reproduces the paper's experimental accounting:
   fastest worker's time;
 * Remark-2.3 wait-out: if the candidate straggler set would push the
   effective pattern outside the scheme's design model, the master waits
-  out *all* stragglers that round (the round costs ``max`` worker time,
-  and nobody is marked a straggler);
+  out the cheapest violating workers until the rest is admissible
+  (``waitout="selective"``), or *all* of them (``"all"``, App. J: the
+  round costs ``max`` worker time, and nobody is marked a straggler);
 * per-round duration: ``min((1+mu)*kappa, max_time)`` without wait-out
   (the master closes the round at the cutoff, cancelling stragglers),
-  ``max_time`` with wait-out;
+  the slowest waited-out worker's time with it;
 * assertion that every job-t decodes by round-(t+T).
+
+The round rule is written once, here: :func:`round_cutoff`,
+:func:`round_duration` / :func:`round_duration_all` on arrays of any
+leading shape (numpy or jax), and :class:`RoundGate` for one run on the
+host.  The batch engines (``core.batch``), the coded trainers
+(``train.driver``) and the harness master (``dist.master``) call them.
 """
 
 from __future__ import annotations
@@ -26,10 +33,15 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from .schemes import Scheme, make_scheme
+from .backend import xp_of
 from .straggler import ConformanceGate, GilbertElliotSource
 
 __all__ = [
     "SimResult",
+    "RoundGate",
+    "round_cutoff",
+    "round_duration",
+    "round_duration_all",
     "simulate",
     "select_parameters",
     "select_parameters_legacy",
@@ -51,6 +63,81 @@ class SimResult:
     @property
     def rounds(self) -> int:
         return len(self.round_times)
+
+
+def round_cutoff(times, mu):
+    """The §2 mu-rule over the last (worker) axis of ``times``, whatever
+    axes lead: ``(cutoff, tmax, base, cand, any_cand)``.  ``cutoff =
+    (1 + mu) * kappa`` with ``kappa`` the fastest time, ``cand`` marks
+    the candidate stragglers ``times > cutoff``, and ``base = min(cutoff,
+    tmax)`` is the round's duration when nobody is waited out.  Numpy
+    and jax arrays alike; the staged scan traces these expressions in
+    this order."""
+    kappa = times.min(axis=-1)
+    cutoff = (1.0 + mu) * kappa
+    tmax = times.max(axis=-1)
+    cand = times > cutoff[..., None]
+    any_cand = cand.any(axis=-1)
+    base = xp_of(times).minimum(cutoff, tmax)
+    return cutoff, tmax, base, cand, any_cand
+
+
+def round_duration(times, cutoff, base, eff, waited, xp):
+    """Remark-2.3 selective wait-out: the round lasts until the slowest
+    waited-out worker, and at least until the cutoff while anyone is
+    still marked a straggler (``base`` then) or the cutoff itself when
+    everyone was waited; ``base`` when nobody was waited.  ``eff`` and
+    ``waited`` are worker masks.  Returns ``(duration, waited_any)``."""
+    waited_any = waited.any(axis=-1)
+    wmax = xp.where(waited, times, -xp.inf).max(axis=-1)
+    dur_w = xp.maximum(wmax, xp.where(eff.any(axis=-1), base, cutoff))
+    return xp.where(waited_any, dur_w, base), waited_any
+
+
+def round_duration_all(tmax, base, any_cand, admitted, xp):
+    """App.-J wait-out: a round whose candidates the gate refuses waits
+    out every worker and lasts ``tmax``; ``base`` otherwise.  Returns
+    ``(duration, waited)``."""
+    wo = any_cand & ~admitted
+    return xp.where(wo, tmax, base), wo
+
+
+class RoundGate:
+    """The round rule on the host for one run: the mu-rule, the
+    wait-out on a :class:`ConformanceGate` (``waitout`` is
+    ``"selective"``, Remark 2.3, or ``"all"``, App. J) and the round's
+    duration, through the same functions as the batch engines."""
+
+    def __init__(self, model, n: int, *, mu: float,
+                 waitout: str = "selective"):
+        self.gate = ConformanceGate(model, n)
+        self.mu = mu
+        self.waitout = waitout
+
+    @property
+    def history(self) -> np.ndarray:
+        """Effective pattern committed so far, (rounds, n) bool."""
+        return self.gate.history
+
+    def round(self, times: np.ndarray) -> tuple[np.ndarray, float, bool]:
+        """Commit one round of worker ``times``: the effective
+        stragglers, the duration and whether the round waited."""
+        cutoff, tmax, base, cand, any_cand = round_cutoff(times, self.mu)
+        if not any_cand:
+            self.gate.force(cand)
+            return cand, float(base), False
+        if self.waitout == "selective":
+            eff, ids = self.gate.admit_partial(cand, times)
+            waited = np.zeros(cand.shape, dtype=bool)
+            waited[ids] = True
+            duration, _ = round_duration(times, cutoff, base, eff, waited, np)
+            return eff, float(duration), bool(ids)
+        admitted = np.bool_(self.gate.admit(cand))
+        duration, wo = round_duration_all(tmax, base, any_cand, admitted, np)
+        if wo:
+            cand = np.zeros_like(cand)
+            self.gate.force(cand)
+        return cand, float(duration), bool(wo)
 
 
 def simulate(
@@ -77,7 +164,7 @@ def simulate(
         )
 
     extra = (scheme.normalized_load - 1.0 / n) * alpha
-    gate = ConformanceGate(scheme.design_model, n)
+    rg = RoundGate(scheme.design_model, n, mu=mu, waitout=waitout)
     round_times = np.zeros(rounds)
     job_done_round: dict[int, int] = {}
     job_done_time: dict[int, float] = {}
@@ -85,28 +172,8 @@ def simulate(
 
     for t in range(1, rounds + 1):
         scheme.assign(t)
-        times = ref_delays[t - 1] + extra
-        kappa = float(times.min())
-        cutoff = (1.0 + mu) * kappa
-        candidate = times > cutoff
-        if not candidate.any():
-            gate.force(candidate)
-            duration = float(min(cutoff, times.max()))
-        elif waitout == "selective":
-            candidate, waited = gate.admit_partial(candidate, times)
-            if waited:
-                waitouts += 1
-                duration = float(max(times[waited].max(), min(cutoff, times.max()) if candidate.any() else cutoff))
-            else:
-                duration = float(min(cutoff, times.max()))
-        else:  # App-J fallback: wait out all workers on violation
-            if gate.admit(candidate):
-                duration = float(min(cutoff, times.max()))
-            else:
-                waitouts += 1
-                candidate = np.zeros(n, dtype=bool)
-                gate.force(candidate)
-                duration = float(times.max())
+        candidate, duration, waited = rg.round(ref_delays[t - 1] + extra)
+        waitouts += waited
         scheme.observe(t, candidate)
         round_times[t - 1] = duration
         elapsed = float(round_times[:t].sum())
@@ -130,7 +197,7 @@ def simulate(
         job_done_round=job_done_round,
         job_done_time=job_done_time,
         waitouts=waitouts,
-        effective_pattern=gate.history,
+        effective_pattern=rg.history,
         normalized_load=scheme.normalized_load,
     )
 

@@ -8,9 +8,10 @@ worker's planned delay, then applies the paper's master protocol on
 REAL wall clock:
 
 * mu-rule: the planned per-round times ``delays[t-1] + (L - 1/n) *
-  alpha`` give the candidate stragglers ``times > (1 + mu) * kappa`` —
-  expression-for-expression the ``simulate_fast`` / trainer loop, so
-  the recording replays bit-identically through the simulator;
+  alpha`` give the candidate stragglers through
+  ``core.simulator.round_cutoff``, the rule ``simulate_fast`` and the
+  trainers apply, so the recording replays bit-identically through the
+  simulator;
 * Remark-2.3 selective wait-out via the stateful ``ConformanceGate``:
   waited-out workers are genuinely waited for (their real results
   arrive and enter the decode), non-admitted stragglers' work is
@@ -70,6 +71,7 @@ from repro.core.schemes import (
     make_scheme,
     normalize_scheme_name,
 )
+from repro.core.simulator import round_cutoff, round_duration
 from repro.core.straggler import ConformanceGate
 from repro.data.synthetic import chunk_boundaries
 
@@ -238,18 +240,6 @@ def _decide(gate: ConformanceGate, cand: np.ndarray,
     if not cand.any():
         return cand.copy(), []
     return copy.deepcopy(gate).admit_partial(cand.copy(), cost)
-
-
-def _analytic_duration(times: np.ndarray, cutoff: float, tmax: float,
-                       cand: np.ndarray, eff: np.ndarray,
-                       waited: list[int]) -> float:
-    """The simulator's round-duration expression on planned times."""
-    if not cand.any():
-        return float(min(cutoff, tmax))
-    if waited:
-        base = float(min(cutoff, tmax)) if eff.any() else cutoff
-        return float(max(times[waited].max(), base))
-    return float(min(cutoff, tmax))
 
 
 def degrade_params(name: str, params: dict | None,
@@ -614,10 +604,7 @@ class _MasterLoop:
                 by_worker[mt.worker].append(item)
 
         times = ep.planned[t - 1]
-        kappa = float(times.min())
-        cutoff = (1.0 + cfg.mu) * kappa
-        tmax = float(times.max())
-        base_cand = times > cutoff
+        cutoff, tmax, base, base_cand, _ = round_cutoff(times, cfg.mu)
         timeout = cfg.round_timeout
         if timeout is None:
             timeout = tmax * cfg.time_scale * 1.5 + 0.25
@@ -756,9 +743,10 @@ class _MasterLoop:
                       # repro: allow[protocol-exhaustiveness]: ledger-event query, not a wire handler — "death" events are appended locally by mark_dead, never sent
                       if ev.get("round") == g and ev["kind"] == "death"]
         rec.duration_s = duration
-        rec.analytic_s = _analytic_duration(
-            times, cutoff, tmax, cand, eff, waited
-        ) * cfg.time_scale
+        waited_row = np.zeros(n_eff, dtype=bool)
+        waited_row[waited] = True
+        analytic, _ = round_duration(times, cutoff, base, eff, waited_row, np)
+        rec.analytic_s = float(analytic) * cfg.time_scale
         self.measured.append(duration)
         self.analytic.append(rec.analytic_s)
 

@@ -18,8 +18,8 @@ The spans, outermost first:
   bucket ``sim.dispatch`` (the launch of its trace slice and its
   program), ``sim.fetch`` (``bytes``; it waits for the upload and the
   program) and ``sim.assemble`` (``cells``); ``sim.runner_build`` on a
-  runner-cache miss.  The per-spec path (``fuse=False``) uploads once
-  per spec;
+  runner-cache miss.  ``simulate_lockstep(backend="jax")`` opens the
+  same spans, ``sim.batch`` aside, for its one-member bucket;
 - trainer (``train.driver.VectorizedCodedTrainer``): ``train.run``
   (``jobs``), ``train.round`` (``t``) per round, and per decoded job
   ``train.job`` (``job``, ``model``) holding ``train.batch``,

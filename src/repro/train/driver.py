@@ -12,8 +12,11 @@ The driver runs the full master protocol with real numerics:
 
 Decode exactness (decoded == full-batch gradient at the snapshot) is
 asserted on demand in tests; the wall clock is simulated from the delay
-profile exactly like ``core.simulator`` so runtimes are comparable
-across schemes while the training itself is genuine.
+profile with the round rule of ``core.simulator`` (``RoundGate``), so
+runtimes are comparable across schemes while the training itself is
+genuine.  The drivers keep a running sum of the round durations; the
+simulator sums its ``round_times`` afresh, so the clocks agree to
+rounding, not to the bit.
 
 Two drivers live here:
 
@@ -155,13 +158,13 @@ class CodedTrainingDriver:
     # -- protocol ----------------------------------------------------------
     def run(self, J: int, delays: np.ndarray):
         """Run J jobs; delays: (>= J+T rounds, n) reference profile."""
-        from repro.core.straggler import ConformanceGate
+        from repro.core.simulator import RoundGate
 
         sch = self.scheme
         n = sch.n
         rounds = J + sch.T
         extra = (sch.normalized_load - 1.0 / n) * self.alpha
-        gate = ConformanceGate(sch.design_model, n)
+        rg = RoundGate(sch.design_model, n, mu=self.mu)
         clock = 0.0
 
         for t in range(1, rounds + 1):
@@ -171,17 +174,8 @@ class CodedTrainingDriver:
                 self._snapshots[t] = jax.tree.map(jnp.copy, self.params[midx])
 
             tasks = sch.assign(t)
-            times = delays[t - 1] + extra
-            kappa = float(times.min())
-            cutoff = (1.0 + self.mu) * kappa
-            cand = times > cutoff
-            if not cand.any():
-                gate.force(cand)
-                clock += float(min(cutoff, times.max()))
-            else:
-                cand, waited = gate.admit_partial(cand, times)  # Remark 2.3
-                base = float(min(cutoff, times.max())) if cand.any() else cutoff
-                clock += float(max(times[waited].max(), base)) if waited else base
+            cand, duration, _ = rg.round(delays[t - 1] + extra)
+            clock += duration
 
             self._execute(tasks, cand)
             sch.observe(t, cand)
@@ -326,10 +320,10 @@ class VectorizedCodedTrainer:
     Trains ``num_models`` transformer LMs (``cfg``) concurrently on
     deterministic ``token_batch`` streams; job-t belongs to model
     ``(t-1) % num_models``.  The straggler gate (mu-rule + Remark-2.3
-    wait-out) and the simulated wall clock match ``core.simulator`` /
-    :class:`CodedTrainingDriver` expression-for-expression, so clocks
-    are comparable across all three.  ``batch_size`` must be divisible
-    by ``scheme.chunk_grid()[0]``.
+    wait-out) and each round's duration come from ``core.simulator``'s
+    ``RoundGate``, as in :class:`CodedTrainingDriver`; the clock is
+    their running sum, so it matches the simulator's to rounding.
+    ``batch_size`` must be divisible by ``scheme.chunk_grid()[0]``.
     """
 
     scheme: Scheme
@@ -414,33 +408,20 @@ class VectorizedCodedTrainer:
     def run(self, J: int, delays: np.ndarray) -> float:
         """Run J jobs against the (>= J+T rounds, n) delay profile;
         returns the simulated wall clock."""
-        from repro.core.straggler import ConformanceGate
+        from repro.core.simulator import RoundGate
 
         sch = self.scheme
         n = sch.n
         rounds = J + sch.T
         extra = (sch.normalized_load - 1.0 / n) * self.alpha
-        gate = ConformanceGate(sch.design_model, n)
+        rg = RoundGate(sch.design_model, n, mu=self.mu)
         clock = 0.0
 
         with tracing.span("train.run", jobs=J):
             for t in range(1, rounds + 1):
                 with tracing.span("train.round", t=t):
-                    times = delays[t - 1] + extra
-                    kappa = float(times.min())
-                    cutoff = (1.0 + self.mu) * kappa
-                    cand = times > cutoff
-                    if not cand.any():
-                        gate.force(cand)
-                        clock += float(min(cutoff, times.max()))
-                    else:
-                        # Remark 2.3
-                        cand, waited = gate.admit_partial(cand, times)
-                        base = (float(min(cutoff, times.max()))
-                                if cand.any() else cutoff)
-                        clock += (float(max(times[waited].max(), base))
-                                  if waited else base)
-
+                    cand, duration, _ = rg.round(delays[t - 1] + extra)
+                    clock += duration
                     sch.step(t, cand)
                     for jd in sch.collect_decodes(t):
                         self._apply(jd)

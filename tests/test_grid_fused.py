@@ -1,8 +1,8 @@
 """Parity + planner suite for the grid-fused jax engine:
 ``simulate_batch(..., backend="jax")`` buckets specs by static shape
 key and runs each bucket as ONE vmapped jitted ``lax.scan``
-(``core.batch``).  Contract: grid-fused results == the per-spec
-``simulate_lockstep`` runners == the numpy oracle, EXACT on the
+(``core.batch``).  Contract: grid-fused results == the numpy lockstep
+engine == the scalar oracle, EXACT on the
 bool/int bookkeeping (done rounds, waitout counts, gate patterns) and
 allclose on float loads/runtimes — across every scheme, both wait-out
 modes, ragged J/T grids forcing multiple buckets, and seed-sensitive
@@ -54,18 +54,21 @@ def test_grid_fused_matches_perspec_and_oracle(waitout):
     n, rounds, cells = 12, 22, 3
     traces = _traces(n, rounds, cells, seed0=20)
     fused = simulate_batch(SPECS, traces, alpha=6.0, waitout=waitout,
-                           backend="jax", fuse=True)
-    perspec = simulate_batch(SPECS, traces, alpha=6.0, waitout=waitout,
-                             backend="jax", fuse=False)
-    oracle = simulate_batch(SPECS, traces, alpha=6.0, waitout=waitout,
-                            backend="numpy")
+                           backend="jax")
+    lockstep = simulate_batch(SPECS, traces, alpha=6.0, waitout=waitout,
+                              backend="numpy")
     for si in range(len(SPECS)):
+        name, params = SPECS[si]
+        T = make_scheme(name, n, 1, **dict(params)).T
+        J = rounds - T
         for c in range(cells):
-            # fused == per-spec staged runners and == the numpy oracle
-            assert_sim_parity(perspec[si, 0, c], fused[si, 0, c],
+            # fused == the numpy lockstep engine == the scalar oracle
+            oracle = simulate_fast(make_scheme(name, n, J, **dict(params)),
+                                   traces[c], alpha=6.0, J=J,
+                                   waitout=waitout)
+            assert_sim_parity(lockstep[si, 0, c], fused[si, 0, c],
                               exact=False)
-            assert_sim_parity(oracle[si, 0, c], fused[si, 0, c],
-                              exact=False)
+            assert_sim_parity(oracle, fused[si, 0, c], exact=False)
 
 
 def test_grid_plan_same_shape_sweep_is_one_bucket():
@@ -110,11 +113,10 @@ def test_grid_single_compile_per_bucket_smoke():
     plan = grid_plan(specs, traces)
     assert len(plan["buckets"]) == 1
     clear_runner_cache()
-    fused = simulate_batch(specs, traces, alpha=6.0, backend="jax",
-                           fuse=True)
+    fused = simulate_batch(specs, traces, alpha=6.0, backend="jax")
     st = cache_stats()
     assert st["compiles"] == len(plan["buckets"]) == 1
-    simulate_batch(specs, traces, alpha=6.0, backend="jax", fuse=True)
+    simulate_batch(specs, traces, alpha=6.0, backend="jax")
     st2 = cache_stats()
     assert st2["compiles"] == st["compiles"]
     assert st2["hits"] > st["hits"]
@@ -140,7 +142,7 @@ def test_grid_ragged_and_strict_false():
     assert len(plan["buckets"]) == 3     # three distinct (J, T) shapes
     assert plan["infeasible"] == [1]     # the rejected spec is reported
     grid = simulate_batch(specs, traces, alpha=6.0, strict=False,
-                          backend="jax", fuse=True)
+                          backend="jax")
     assert all(r is None for r in grid[1].ravel())
     for i in (0, 2, 3):
         name, params = specs[i]
@@ -169,7 +171,7 @@ def test_grid_seed_sensitive_fanout():
         specs = [(SEEDED_UNCODED, {}), ("gc", {"s": 3})]
         seeds = (0, 1, 2)
         fused = simulate_batch(specs, traces, seeds=seeds, alpha=6.0,
-                               backend="jax", fuse=True)
+                               backend="jax")
         ref = simulate_batch(specs, traces, seeds=seeds, alpha=6.0,
                              backend="numpy")
         for si in range(len(specs)):
@@ -186,7 +188,7 @@ def test_grid_seed_sensitive_fanout():
 
 
 def test_grid_unsupported_gate_falls_back():
-    """Specs the fused path cannot stage route to the per-spec fallback
+    """Specs the fused path cannot stage route to the numpy engine
     transparently (planner ``fallback`` + identical results)."""
     from repro.core import NoCodingScheme, register_scheme
     from repro.core.kernel import _KERNELS, UncodedKernel, register_kernel
@@ -225,8 +227,7 @@ def test_grid_unsupported_gate_falls_back():
         plan = grid_plan(specs, traces)
         assert plan["fallback"] == [0]
         assert len(plan["buckets"]) == 1
-        fused = simulate_batch(specs, traces, alpha=6.0, backend="jax",
-                               fuse=True)
+        fused = simulate_batch(specs, traces, alpha=6.0, backend="jax")
         ref = simulate_batch(specs, traces, alpha=6.0, backend="numpy")
         for si in range(2):
             for c in range(2):
@@ -242,8 +243,7 @@ def test_grid_fused_dead_lanes_do_not_poison_bucket(waitout):
     """strict=False dead-lane handling on the FUSED path: a spec whose
     lanes die mid-run (wait-out contract violated) shares a vmap bucket
     with healthy specs; the dead lanes must yield None and the sibling
-    specs' results must stay identical to the per-spec staged runners
-    and the numpy oracle."""
+    specs' results must stay identical to the numpy engine."""
     from repro.core.testing import (
         FRAGILE_GC,
         register_fragile_gc,
@@ -267,23 +267,18 @@ def test_grid_fused_dead_lanes_do_not_poison_bucket(waitout):
         assert plan["buckets"][0]["fused"] == ["s", "d"]
 
         fused = simulate_batch(specs, traces, alpha=6.0, waitout=waitout,
-                               strict=False, backend="jax", fuse=True)
-        perspec = simulate_batch(specs, traces, alpha=6.0, waitout=waitout,
-                                 strict=False, backend="jax", fuse=False)
+                               strict=False, backend="jax")
         oracle = simulate_batch(specs, traces, alpha=6.0, waitout=waitout,
                                 strict=False, backend="numpy")
         # the doomed spec actually died somewhere (else the fixture
-        # tests nothing), and None-ness agrees across all three paths
+        # tests nothing), and None-ness agrees across both engines
         assert any(r is None for r in fused[1].ravel())
         for si in range(len(specs)):
             for c in range(cells):
                 assert (fused[si, 0, c] is None) \
-                    == (perspec[si, 0, c] is None) \
                     == (oracle[si, 0, c] is None)
                 if fused[si, 0, c] is None:
                     continue
-                assert_sim_parity(perspec[si, 0, c], fused[si, 0, c],
-                                  exact=False)
                 assert_sim_parity(oracle[si, 0, c], fused[si, 0, c],
                                   exact=False)
         # sibling specs' lanes are fully healthy end to end
@@ -310,8 +305,7 @@ def test_grid_new_kernels_fuse_and_match():
     assert len(plan["buckets"]) == 2
     assert all(b["fused"] == ["s"] for b in plan["buckets"])
     clear_runner_cache()
-    fused = simulate_batch(specs, traces, alpha=6.0, backend="jax",
-                           fuse=True)
+    fused = simulate_batch(specs, traces, alpha=6.0, backend="jax")
     assert cache_stats()["compiles"] == 2
     oracle = simulate_batch(specs, traces, alpha=6.0, backend="numpy")
     for si in range(len(specs)):
@@ -336,8 +330,7 @@ def test_grid_fused_heterogeneous_alpha():
     ])
     specs = [("gc", {"s": s, "prefer_rep": False}) for s in (3, 4, 5)] \
         + [("dc-gc", {"C": 3, "s": 2})]
-    fused = simulate_batch(specs, traces, alpha=alpha, backend="jax",
-                           fuse=True)
+    fused = simulate_batch(specs, traces, alpha=alpha, backend="jax")
     oracle = simulate_batch(specs, traces, alpha=alpha, backend="numpy")
     for si in range(len(specs)):
         for c in range(cells):
@@ -355,11 +348,12 @@ SLICED_J = 10
 
 
 def _fused_equals_perspec(traces):
-    """Fused results against the per-spec runners (each uploads its own
-    slice): exact bookkeeping and round times bit-equal on the CPU."""
-    kw = dict(J=SLICED_J, alpha=6.0, backend="jax")
-    fused = simulate_batch(SLICED, traces, fuse=True, **kw)
-    perspec = simulate_batch(SLICED, traces, fuse=False, **kw)
+    """Fused results (each bucket a device slice of one upload) against
+    the numpy engine: exact bookkeeping and round times bit-equal on the
+    CPU."""
+    kw = dict(J=SLICED_J, alpha=6.0)
+    fused = simulate_batch(SLICED, traces, backend="jax", **kw)
+    perspec = simulate_batch(SLICED, traces, backend="numpy", **kw)
     for p, f in zip(perspec.flat, fused.flat, strict=True):
         assert_sim_parity(p, f, exact=False)
         assert f.round_times.tobytes() == p.round_times.tobytes()
@@ -381,14 +375,13 @@ def test_grid_fused_buckets_slice_one_upload(monkeypatch):
         jax, "device_put",
         lambda x, *a, **k: uploads.append(np.shape(x)) or real(x, *a, **k),
     )
-    simulate_batch(SLICED, traces, J=SLICED_J, alpha=6.0, backend="jax",
-                   fuse=True)
+    simulate_batch(SLICED, traces, J=SLICED_J, alpha=6.0, backend="jax")
     assert uploads == [traces.shape]
 
 
 def test_grid_fused_upload_keeps_f64():
     """The one upload carries the delays at f64: an f32 copy would give
-    the fused buckets other round times than the per-spec runners."""
+    the fused buckets other round times than the numpy engine."""
     n, cells = 12, 2
     traces = np.random.default_rng(5).exponential(
         1.0, size=(cells, SLICED_J + 3 + 5, n))
@@ -396,13 +389,9 @@ def test_grid_fused_upload_keeps_f64():
     assert (as_f32 != traces).all()
     fused = _fused_equals_perspec(traces)
     rounded = simulate_batch(SLICED, as_f32, J=SLICED_J, alpha=6.0,
-                             backend="jax", fuse=False)
+                             backend="numpy")
     assert all(f.round_times.tobytes() != r.round_times.tobytes()
                for f, r in zip(fused.flat, rounded.flat, strict=True))
-
-
-# (the REPRO_GRID_FUSE toggle/parser matrix lives in
-# tests/test_runner_cache.py::test_grid_fuse_env_parser)
 
 
 @pytest.mark.slow
@@ -418,7 +407,7 @@ def test_grid_fused_large_n_pallas_path(waitout):
              ("sr-sgc", dict(B=1, W=2, lam=11)),
              ("gc", dict(s=7))]
     fused = simulate_batch(specs, traces, alpha=6.0, waitout=waitout,
-                           backend="jax", fuse=True)
+                           backend="jax")
     for si, (name, kw) in enumerate(specs):
         T = make_scheme(name, n, 1, **dict(kw)).T
         J = rounds - T

@@ -150,9 +150,15 @@ def test_runner_cache_cap_and_eviction(monkeypatch):
 def test_jax_runner_cache_invalidated_on_reregistration():
     """Re-registering a scheme's kernel must change the runner key, so
     the extension API's register/unregister pattern never hits a stale
-    compiled runner (or a stale 'unsupported' verdict)."""
-    from repro.core.batch import _jax_runner_key
-    from repro.core.kernel import _KERNELS, UncodedKernel, register_kernel
+    compiled runner."""
+    from repro.core import get_backend
+    from repro.core.batch import _bucket_key
+    from repro.core.kernel import (
+        _KERNELS,
+        UncodedKernel,
+        make_kernel,
+        register_kernel,
+    )
     from repro.core.schemes import _SCHEME_FACTORIES
     from repro.core.testing import (
         SeededUncodedScheme,
@@ -160,17 +166,21 @@ def test_jax_runner_cache_invalidated_on_reregistration():
         unregister_testing_schemes,
     )
 
+    def key(scheme):
+        kern = make_kernel(scheme, get_backend("numpy"))
+        return _bucket_key(scheme, kern, {}, 4, "selective", 2, 0)
+
     register_testing_schemes()
     try:
         scheme = SeededUncodedScheme(8, 4)
-        key1 = _jax_runner_key(scheme, {}, 4, "selective", 0)
+        key1 = key(scheme)
 
         class Replacement(UncodedKernel):
             name = scheme.name
             seed_sensitive = True
 
         register_kernel(scheme.name, Replacement)
-        key2 = _jax_runner_key(scheme, {}, 4, "selective", 0)
+        key2 = key(scheme)
         assert key1 != key2
     finally:
         unregister_testing_schemes()

@@ -1,21 +1,14 @@
-"""Host-side regression tests for the compiled-runner cache and the
-``core.batch`` environment parsers (no jax required).
+"""Host-side regression tests for the compiled-runner cache and its
+``REPRO_RUNNER_CACHE_CAP`` parser (no jax required).
 
-The runner cache is one FIFO shared by per-spec and per-bucket
-compiled runners; "unsupported spec" verdicts are cached in a SIDE
-table exempt from the ``REPRO_RUNNER_CACHE_CAP`` cap — a long mixed
-sweep interleaving many unstageable specs with a few compiled ones
-must never evict the hot compiled runners (the PR-5 regression).
+The runner cache is one FIFO of compiled bucket runners, capped by
+``REPRO_RUNNER_CACHE_CAP``.
 """
-
-import warnings
 
 import pytest
 
 from repro.core import batch
 from repro.core.batch import (
-    _JAX_UNSUPPORTED,
-    _fuse_enabled,
     _runner_cache_cap,
     _runner_cache_lookup,
     cache_stats,
@@ -30,34 +23,6 @@ def _clean_cache():
     clear_runner_cache()
 
 
-def test_unsupported_verdicts_exempt_from_cap(_clean_cache, monkeypatch):
-    """Verdict entries must not count toward the FIFO cap nor evict
-    compiled runners, and must still be cache hits on re-lookup."""
-    monkeypatch.setenv("REPRO_RUNNER_CACHE_CAP", "2")
-    _runner_cache_lookup(("spec", "a"), lambda: ("runner-a", "a"))
-    _runner_cache_lookup(("spec", "b"), lambda: ("runner-b", "b"))
-    # a long run of unsupported specs (pre-fix these filled the FIFO
-    # and pushed both compiled runners out)
-    for i in range(8):
-        got = _runner_cache_lookup(
-            ("spec", f"unsupported-{i}"), lambda: _JAX_UNSUPPORTED
-        )
-        assert got is _JAX_UNSUPPORTED
-    st = cache_stats()
-    assert st["size"] == 2          # both compiled runners still cached
-    assert st["unsupported"] == 8   # verdicts tracked in the side table
-    assert st["evictions"] == 0
-    assert st["compiles"] == 2
-    # the compiled runners are hits — build() must not run again
-    hits0 = cache_stats()["hits"]
-    assert _runner_cache_lookup(("spec", "a"), _fail)[0] == "runner-a"
-    assert _runner_cache_lookup(("spec", "b"), _fail)[0] == "runner-b"
-    # verdicts re-hit without re-deriving
-    assert _runner_cache_lookup(("spec", "unsupported-0"), _fail) \
-        is _JAX_UNSUPPORTED
-    assert cache_stats()["hits"] == hits0 + 3
-
-
 def _fail():  # pragma: no cover - called only on a cache-miss bug
     raise AssertionError("cache miss: build() re-ran for a cached key")
 
@@ -66,23 +31,30 @@ def test_compiled_runner_fifo_still_capped(_clean_cache, monkeypatch):
     """The cap still governs compiled runners themselves."""
     monkeypatch.setenv("REPRO_RUNNER_CACHE_CAP", "2")
     for i in range(4):
-        _runner_cache_lookup(("spec", i), lambda i=i: (f"runner-{i}", ""))
+        _runner_cache_lookup(("grid", i), lambda i=i: (f"runner-{i}", ""))
     st = cache_stats()
     assert st["size"] == 2
     assert st["evictions"] == 2
     # FIFO: the two oldest runners were evicted
     rebuilt = []
-    _runner_cache_lookup(("spec", 0), lambda: rebuilt.append(0) or ("r", ""))
+    _runner_cache_lookup(("grid", 0), lambda: rebuilt.append(0) or ("r", ""))
     assert rebuilt == [0]
 
 
 def test_clear_runner_cache_drops_verdicts(_clean_cache):
-    _runner_cache_lookup(("spec", "u"), lambda: _JAX_UNSUPPORTED)
-    assert cache_stats()["unsupported"] == 1
+    """Clearing drops every compiled runner and zeroes the counters."""
+    _runner_cache_lookup(("grid", "a"), lambda: ("runner-a", "a"))
+    _runner_cache_lookup(("grid", "a"), _fail)
+    assert cache_stats()["size"] == 1
     clear_runner_cache()
     st = cache_stats()
-    assert st["unsupported"] == 0 and st["size"] == 0
-    assert st["hits"] == st["misses"] == 0
+    assert st["size"] == 0
+    assert st["hits"] == st["misses"] == st["compiles"] == 0
+    assert st["evictions"] == 0
+    rebuilt = []
+    _runner_cache_lookup(("grid", "a"),
+                         lambda: rebuilt.append(1) or ("runner-a", "a"))
+    assert rebuilt == [1]
 
 
 def test_runner_cache_cap_env_parser(monkeypatch):
@@ -95,24 +67,3 @@ def test_runner_cache_cap_env_parser(monkeypatch):
     monkeypatch.setenv("REPRO_RUNNER_CACHE_CAP", "not-an-int")
     with pytest.warns(UserWarning, match="REPRO_RUNNER_CACHE_CAP"):
         assert _runner_cache_cap() == batch._RUNNER_CACHE_CAP_DEFAULT
-
-
-def test_grid_fuse_env_parser(monkeypatch):
-    monkeypatch.delenv("REPRO_GRID_FUSE", raising=False)
-    assert _fuse_enabled(None) is True
-    # explicit per-call values bypass the env entirely
-    assert _fuse_enabled(False) is False
-    assert _fuse_enabled(True) is True
-    for off in ("0", "false", "OFF", " no "):
-        monkeypatch.setenv("REPRO_GRID_FUSE", off)
-        assert _fuse_enabled(None) is False
-    for on in ("1", "true", "ON", "yes", ""):
-        monkeypatch.setenv("REPRO_GRID_FUSE", on)
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            assert _fuse_enabled(None) is True
-    # the PR-5 regression: a typo'd value used to silently mean ON
-    for typo in ("nope", "n0", "disable", "fuse=0"):
-        monkeypatch.setenv("REPRO_GRID_FUSE", typo)
-        with pytest.warns(UserWarning, match="REPRO_GRID_FUSE"):
-            assert _fuse_enabled(None) is True

@@ -16,8 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import make_scheme
-from repro.core.batch import _build_jax_runner
+from repro.core import get_backend, make_kernel, make_scheme
+from repro.core.batch import _build_jax_grid_runner
 from repro.kernels.gate_window import ops
 
 N = 256
@@ -114,17 +114,27 @@ def test_gate_kernel_vmapped_spec_axis_compiles_natively(one_chip, which):
 ])
 def test_lockstep_runner_compiles_with_native_gate(one_chip, name, params,
                                                    windowed):
-    """The Table-1 specs' jitted scans at n=256, x64 timing math
+    """The Table-1 specs' jitted scans at n=256, each a one-lane bucket
+    as ``simulate_lockstep(backend="jax")`` runs it, x64 timing math
     included, compile for the chip; windowed gates call the native
     Pallas kernel."""
     J, cells = 32, 8
     sch = make_scheme(name, N, J, **params)
-    runner, _ = _build_jax_runner(sch, J, "selective")
+    kern = make_kernel(sch, get_backend("numpy"))
+    fused_names = tuple(kern.fused_params)
+    runner, _ = _build_jax_grid_runner(sch, J, "selective", fused_names)
     with jax.enable_x64(True):
+        def lane(value):
+            return jax.ShapeDtypeStruct((1,), np.asarray(value).dtype,
+                                        sharding=one_chip)
+
         traces = jax.ShapeDtypeStruct((cells, J + sch.T, N), jnp.float64,
                                       sharding=one_chip)
-        text = runner.lower(traces, 1.0, np.float64(8.0),
-                            sch.normalized_load).compile().as_text()
+        scalars = kern.fused_scalars(sch)
+        text = runner.lower(
+            lane(1.0), lane(8.0), lane(sch.normalized_load),
+            {k: lane(scalars[k]) for k in fused_names}, traces,
+        ).compile().as_text()
     assert ("tpu_custom_call" in text) == windowed
 
 

@@ -11,7 +11,7 @@ import pytest
 
 from repro import tracing
 from repro.configs.qwen2_0_5b import SMOKE
-from repro.core import batch, make_scheme, simulate_batch
+from repro.core import batch, make_scheme, simulate_batch, simulate_lockstep
 from repro.core.batch import clear_runner_cache
 from repro.train import VectorizedCodedTrainer
 
@@ -27,7 +27,7 @@ def _traces():
 
 
 def _sweep():
-    return simulate_batch(SPECS, _traces(), J=J, backend="jax", fuse=True)
+    return simulate_batch(SPECS, _traces(), J=J, backend="jax")
 
 
 def _trainer():
@@ -132,16 +132,20 @@ def test_trainer_spans_nest_under_each_job(tmp_path):
 
 
 def test_per_spec_spans_share_the_call_id(tmp_path):
-    run = lambda: simulate_batch(SPECS, _traces(), J=J, backend="jax",
-                                 fuse=False)
+    """One spec through ``simulate_lockstep(backend="jax")``: a
+    one-member bucket whose spans all carry one call id."""
+    name, params = SPECS[0]
+    run = lambda: simulate_lockstep(name, params, _traces(), J=J,
+                                    backend="jax")
     run()                           # compiles outside the trace
     _, events = _profiled(tmp_path, run)
     sim = _named(events, "sim.")
-    (top,) = [e for e in sim if e[0] == "sim.batch"]
-    names = [e[0] for e in sim]
-    assert names.count("sim.dispatch") == len(SPECS)
-    assert {e[3]["call"] for e in sim} == {top[3]["call"]}
-    assert all(_inside(e, top) for e in sim)
+    names = sorted(e[0] for e in sim)
+    assert names == ["sim.assemble", "sim.dispatch", "sim.fetch",
+                     "sim.plan", "sim.upload"]
+    assert len({e[3]["call"] for e in sim}) == 1
+    (upload,) = [e for e in sim if e[0] == "sim.upload"]
+    assert upload[3]["buckets"] == 1
 
 
 def test_span_counters_are_left_out_without_a_profiler(monkeypatch):
